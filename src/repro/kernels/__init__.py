@@ -5,7 +5,8 @@
 #   ssd:             Mamba-2 chunked state-space-duality scan
 #   rglru:           RecurrentGemma RG-LRU linear recurrence
 # Each has kernel.py (pl.pallas_call + BlockSpec), ops.py (dispatching jit
-# wrapper with an XLA fallback used on CPU), and ref.py (pure-jnp oracle).
+# wrapper: "auto" picks the kernel on a TPU and the XLA path elsewhere;
+# "pallas" raises off the TPU), and ref.py (pure-jnp oracle).
 
 from . import flash_attention, rglru, ssd
 

@@ -20,6 +20,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -29,8 +30,8 @@ def _fa_kernel(
     q_ref,        # (1, 1, bq, D)
     k_ref,        # (1, 1, bk, D)
     v_ref,        # (1, 1, bk, D)
-    qseg_ref,     # (1, bq)
-    kseg_ref,     # (1, bk)
+    qseg_ref,     # (1, bq, 1)
+    kseg_ref,     # (1, 1, bk)
     o_ref,        # (1, 1, bq, D)
     m_scr,        # (bq,) f32 scratch
     l_scr,        # (bq,) f32
@@ -85,9 +86,7 @@ def _fa_kernel(
         if window is not None:
             mask &= (q_pos - k_pos) < window
         if use_segments:
-            qs = qseg_ref[0]                                  # (bq,)
-            ks = kseg_ref[0]                                  # (bk,)
-            mask &= qs[:, None] == ks[None, :]
+            mask &= qseg_ref[0] == kseg_ref[0]               # (bq,1)==(1,bk)
         s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_scr[...]
@@ -156,7 +155,11 @@ def flash_attention_pallas(
         use_segments=use_segments, scale=scale, block_q=block_q,
         block_k=block_k, n_k=n_k, q_offset=q_offset,
     )
-    out = _call(kernel, qt, kt, vt, q_segments, kv_segments,
+    # Segment ids as a column for q and a row for kv: each block's last two
+    # dims are then (aligned, whole) or (whole, aligned).
+    qseg = q_segments.astype(jnp.int32).reshape(B, Sq, 1)
+    kseg = kv_segments.astype(jnp.int32).reshape(B, 1, Sk)
+    out = _call(kernel, qt, kt, vt, qseg, kseg,
                 B, Hq, n_q, n_k, block_q, block_k, D, group,
                 q.dtype, interpret)
     return out.transpose(0, 2, 1, 3)
@@ -164,10 +167,6 @@ def flash_attention_pallas(
 
 def _call(kernel, qt, kt, vt, qseg, kseg, B, Hq, n_q, n_k, block_q, block_k,
           D, group, dtype, interpret):
-    from jax.experimental.pallas import tpu as pltpu
-
-    from .._compat import CompilerParams as _CompilerParams
-
     return pl.pallas_call(
         kernel,
         grid=(B, Hq, n_q, n_k),
@@ -177,8 +176,8 @@ def _call(kernel, qt, kt, vt, qseg, kseg, B, Hq, n_q, n_k, block_q, block_k,
                          lambda b, h, qi, ki, g=group: (b, h // g, ki, 0)),
             pl.BlockSpec((1, 1, block_k, D),
                          lambda b, h, qi, ki, g=group: (b, h // g, ki, 0)),
-            pl.BlockSpec((1, block_q), lambda b, h, qi, ki: (b, qi)),
-            pl.BlockSpec((1, block_k), lambda b, h, qi, ki: (b, ki)),
+            pl.BlockSpec((1, block_q, 1), lambda b, h, qi, ki: (b, qi, 0)),
+            pl.BlockSpec((1, 1, block_k), lambda b, h, qi, ki: (b, 0, ki)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, D),
                                lambda b, h, qi, ki: (b, h, qi, 0)),
@@ -188,7 +187,7 @@ def _call(kernel, qt, kt, vt, qseg, kseg, B, Hq, n_q, n_k, block_q, block_k,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),

@@ -1,6 +1,7 @@
 """Public attention op with implementation dispatch.
 
-- ``impl="pallas"``: the TPU kernel (``interpret=True`` on CPU for tests).
+- ``impl="pallas"``: the TPU kernel; raises off the TPU.
+- ``impl="pallas_interpret"``: the same kernel in interpret mode (CPU tests).
 - ``impl="xla"``: memory-efficient chunked flash in pure jnp (nested scans,
   online softmax) — used for dry-run lowering on CPU and as a safe fallback;
   never materializes (Sq, Sk).
@@ -19,6 +20,9 @@ import jax.numpy as jnp
 
 from .kernel import flash_attention_pallas
 from .ref import attention_reference
+
+_NO_TPU = ("impl='pallas' needs a TPU backend; "
+           "impl='pallas_interpret' runs the kernel in interpret mode")
 
 __all__ = ["flash_attention"]
 
@@ -58,14 +62,12 @@ def flash_attention(
                   q_offset=q_offset, scale=scale)
     if impl == "naive":
         return attention_reference(q, k, v, **common)
-    if impl == "pallas":
+    if impl in ("pallas", "pallas_interpret"):
+        if impl == "pallas" and jax.default_backend() != "tpu":
+            raise RuntimeError(_NO_TPU)
         return flash_attention_pallas(
             q, k, v, block_q=block_q, block_k=block_k,
-            interpret=jax.default_backend() != "tpu", **common)
-    if impl == "pallas_interpret":
-        return flash_attention_pallas(
-            q, k, v, block_q=block_q, block_k=block_k, interpret=True,
-            **common)
+            interpret=impl == "pallas_interpret", **common)
     if impl == "xla":
         return _flash_xla(q, k, v, block_q=block_q, block_k=block_k, **common)
     raise ValueError(f"unknown impl {impl!r}")

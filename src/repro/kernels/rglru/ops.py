@@ -10,6 +10,9 @@ import jax.numpy as jnp
 from .kernel import rglru_pallas
 from .ref import RGLRU_C, rglru_reference, rglru_step_reference
 
+_NO_TPU = ("impl='pallas' needs a TPU backend; "
+           "impl='pallas_interpret' runs the kernel in interpret mode")
+
 __all__ = ["rglru", "rglru_step"]
 
 
@@ -31,10 +34,11 @@ def rglru(
     if impl == "ref":
         return rglru_reference(x, r, i, lam, initial_h)
     if impl in ("pallas", "pallas_interpret"):
+        if impl == "pallas" and jax.default_backend() != "tpu":
+            raise RuntimeError(_NO_TPU)
         return rglru_pallas(
             x, r, i, lam, initial_h, chunk=chunk,
-            interpret=(impl == "pallas_interpret"
-                       or jax.default_backend() != "tpu"))
+            interpret=impl == "pallas_interpret")
     if impl == "xla":
         return _rglru_xla(x, r, i, lam, initial_h)
     raise ValueError(f"unknown impl {impl!r}")

@@ -10,6 +10,9 @@ import jax.numpy as jnp
 from .kernel import ssd_pallas
 from .ref import ssd_reference, ssd_step_reference
 
+_NO_TPU = ("impl='pallas' needs a TPU backend; "
+           "impl='pallas_interpret' runs the kernel in interpret mode")
+
 __all__ = ["ssd", "ssd_step"]
 
 
@@ -33,10 +36,11 @@ def ssd(
     if impl == "ref":
         return ssd_reference(x, a, B_mat, C_mat, initial_state)
     if impl in ("pallas", "pallas_interpret"):
+        if impl == "pallas" and jax.default_backend() != "tpu":
+            raise RuntimeError(_NO_TPU)
         return ssd_pallas(
             x, a, B_mat, C_mat, initial_state, chunk=chunk,
-            interpret=(impl == "pallas_interpret"
-                       or jax.default_backend() != "tpu"))
+            interpret=impl == "pallas_interpret")
     if impl == "xla":
         return _ssd_xla(x, a, B_mat, C_mat, initial_state, chunk=chunk)
     raise ValueError(f"unknown impl {impl!r}")
@@ -72,8 +76,11 @@ def _ssd_xla(x, a, B_mat, C_mat, initial_state, *, chunk):
     scores = jnp.einsum("bgtn,bgrn->bgtr", Cf, Bf)       # (B, nc, c, c)
     t_idx = jnp.arange(chunk)
     causal = (t_idx[:, None] >= t_idx[None, :])
-    decay = jnp.exp(la[:, :, :, None, :] - la[:, :, None, :, :])  # (B,nc,c,c,H)
-    m = jnp.where(causal[None, None, :, :, None], decay, 0.0)
+    # Mask the exponent, not the exp: above the diagonal la_t - la_r > 0
+    # overflows at long chunks, and inf * 0 in the backward is NaN.
+    m = jnp.exp(jnp.where(causal[None, None, :, :, None],
+                          la[:, :, :, None, :] - la[:, :, None, :, :],
+                          -jnp.inf))                      # (B,nc,c,c,H)
     y_intra = jnp.einsum("bgtr,bgtrh,bgrhp->bgthp", scores, m, xf)
 
     # Chunk -> state contribution (independent per chunk).

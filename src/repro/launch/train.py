@@ -12,8 +12,11 @@ Flow (exactly Fig. 1 of the disclosure):
 Fault tolerance: training resumes exactly from (checkpoint, loader state);
 ``--kill-at`` demonstrates a mid-run crash + restart recovering bit-exact.
 
-This driver runs a REDUCED config on local devices (CPU here); the
-production meshes are exercised by dryrun.py (same code path, bigger mesh).
+``main`` runs the whole config (or ``--smoke``'s reduced one) in float32
+on every local device; ``train`` is the same loop for a caller that brings
+its own config, runtime and mesh, as ``chip_smoke.py`` does for a
+depth-cut mamba2-1.3b in bf16 on one TPU chip.  The step is differentiated,
+so it names XLA implementations: the Pallas kernels have no backward.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.train --arch mamba2-1.3b \
@@ -23,7 +26,9 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import os
 import time
+from pathlib import Path
 from typing import Optional
 
 import jax
@@ -40,9 +45,25 @@ from ..models import RuntimeConfig, build_model
 from ..train import (TrainConfig, load_checkpoint, make_train_step,
                      save_checkpoint)
 from ..train.optimizer import OptimizerConfig, make_optimizer
+from ..train.checkpoint import checkpoint_node_id, latest_step
 from ..train.sharding import (ActivationSharding, ShardingRules, batch_specs,
                               named, opt_state_specs, param_specs)
 from .mesh import make_local_mesh
+
+REPO_ROOT = Path(__file__).resolve().parents[3]
+
+
+def setup_compile_cache() -> str:
+    """Keep JAX's persistent compilation cache at a fixed path.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is used as is (JAX reads it
+    itself); otherwise the cache goes to ``<repo>/.jax_cache``.  Call at the
+    start of an entry point, before anything compiles."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(REPO_ROOT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def synthetic_corpus(n_docs: int = 256, seed: int = 0):
@@ -57,11 +78,11 @@ def synthetic_corpus(n_docs: int = 256, seed: int = 0):
     return docs
 
 
-def build_platform(seq_len: int, n_docs: int = 256):
+def build_platform(seq_len: int, n_docs: int = 256, seed: int = 0):
     """Stand up the platform and run the Fig. 1 pipelines."""
     plat = Platform.open(actor="trainer")
     plat.dataset("corpus/raw").check_in(
-        synthetic_corpus(n_docs), actor="ingest",
+        synthetic_corpus(n_docs, seed), actor="ingest",
         message="pipeline A: ingest")
     plat.register(Workflow(
         name="tokenize-pack",
@@ -77,13 +98,15 @@ def build_platform(seq_len: int, n_docs: int = 256):
     return plat, run
 
 
-def main(argv=None) -> dict:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mamba2-1.3b")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq-len", type=int, default=128)
     ap.add_argument("--lr", type=float, default=3e-3)
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the synthetic corpus and the weights")
     ap.add_argument("--smoke", action="store_true",
                     help="use the reduced same-family config")
     ap.add_argument("--kill-at", type=int, default=None,
@@ -97,22 +120,51 @@ def main(argv=None) -> dict:
                          "above the size threshold, else legacy global)")
     ap.add_argument("--window-pages", type=int, default=8,
                     help="page-window shuffle width (pages per window)")
-    args = ap.parse_args(argv)
+    return ap
 
+
+def main(argv=None) -> dict:
+    args = build_parser().parse_args(argv)
+    setup_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    mesh = make_local_mesh()
+    rt = RuntimeConfig(compute_dtype=jnp.float32, attn_impl="naive",
+                       ssd_impl="xla", rglru_impl="xla")
+    return train(cfg, args, rt)
+
+
+def _tree_bit_equal(a, b) -> bool:
+    def raw(x):
+        return np.ascontiguousarray(np.asarray(x)).view(np.uint8)
+
+    return all(np.array_equal(raw(x), raw(y))
+               for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
+
+
+def train(cfg, args: argparse.Namespace, rt: RuntimeConfig,
+          mesh=None) -> dict:
+    """Fig. 1 end to end: ingest, tokenize/pack, plan, feed, train, and
+    check the checkpoints back in.  ``args`` are ``build_parser()``'s;
+    ``mesh`` defaults to every local device."""
+    for name in ("attn_impl", "ssd_impl", "rglru_impl"):
+        impl = getattr(rt, name)
+        if impl == "auto" or impl.startswith("pallas"):
+            raise ValueError(f"{name}={impl!r}: the train step is "
+                             "differentiated and the Pallas kernels have no "
+                             "backward; name an XLA implementation")
+    mesh = mesh if mesh is not None else make_local_mesh()
     rules = ShardingRules(mesh, batch_axes=("data",), fsdp_axis=None,
                           tp_axis=None)
-    rt = RuntimeConfig(compute_dtype=jnp.float32, attn_impl="naive",
-                       ssd_impl="xla", rglru_impl="xla",
-                       act_sharding=ActivationSharding(rules))
+    rt = rt.with_(act_sharding=ActivationSharding(rules))
     model = build_model(cfg, rt)
 
+    t0 = time.perf_counter()
     plat, wf_run = build_platform(args.seq_len, n_docs=max(
-        args.batch * 8, 128))
+        args.batch * 8, 128), seed=args.seed)
     dm = plat.manager
     snap = plat.dataset("corpus/packed").checkout()
-    print(f"platform: snapshot {snap.snapshot_id} with {len(snap)} packs")
+    ingest_s = time.perf_counter() - t0
+    print(f"platform: snapshot {snap.snapshot_id} with {len(snap)} packs "
+          f"({ingest_s:.2f} s ingest + tokenize/pack)")
 
     # The loader feeds from the lazy plan (page-granular read surface; the
     # registered snapshot above carries lineage) — page-window streaming
@@ -123,19 +175,33 @@ def main(argv=None) -> dict:
     train_cfg = TrainConfig(optimizer=OptimizerConfig(
         name="adamw", lr=args.lr, warmup_steps=10, total_steps=args.steps))
     opt = make_optimizer(train_cfg.optimizer)
-    step_fn = jax.jit(make_train_step(model, train_cfg),
-                      donate_argnums=(0, 1))
 
-    params = model.init(jax.random.PRNGKey(0))
-    opt_state = opt.init(params)
+    key = jax.random.PRNGKey(args.seed)
+    like_p = jax.eval_shape(lambda: model.init(key))
+    like_o = jax.eval_shape(opt.init, like_p)
+    pspecs = param_specs(like_p, rules)
+    p_sh = named(mesh, pspecs)
+    o_sh = named(mesh, opt_state_specs(like_o, like_p, pspecs, rules))
+    n_params = sum(x.size for x in jax.tree.leaves(like_p))
+    print(f"model: {cfg.name} {cfg.n_layers} layers d_model {cfg.d_model} "
+          f"params {n_params} compute {jnp.dtype(rt.compute_dtype).name} "
+          f"remat {rt.remat} on {mesh.devices.size} device(s)")
+    print(f"step impls: attn={rt.attn_impl} ssd={rt.ssd_impl} "
+          f"rglru={rt.rglru_impl}")
+    params = jax.jit(model.init, out_shardings=p_sh)(key)
+    opt_state = jax.jit(opt.init, out_shardings=o_sh)(params)
+    step_jit = jax.jit(make_train_step(model, train_cfg),
+                       donate_argnums=(0, 1))
     run_node = f"train_run:{int(time.time())}"
     dm.lineage.add_node(run_node, NodeKind.WORKFLOW_RUN, kind_detail="train",
                         arch=cfg.name)
     dm.lineage.add_edge(snap.snapshot_id, run_node, "input_to")
     dm.lineage.flush()
 
-    losses = []
+    losses, step_s, save_s = [], [], []
     step = 0
+    step_fn = None          # the train step, compiled for the first batch
+    out = {"ingest_s": ingest_s, "n_params": n_params}
 
     from jax.sharding import NamedSharding
 
@@ -148,38 +214,66 @@ def main(argv=None) -> dict:
         next batch's host decode AND device transfer overlap the current
         train_step, and each yielded batch carries the loader state that
         makes its checkpoint bit-exact to resume."""
-        nonlocal params, opt_state, step
+        nonlocal params, opt_state, step, step_fn
         if step >= until:
             return
         feed_it = iter(DeviceFeed(loader, sharding_fn=batch_shardings))
         try:
             while step < until:
                 batch, loader_state = next(feed_it)
+                if step_fn is None:
+                    t = time.perf_counter()
+                    step_fn = step_jit.lower(params, opt_state,
+                                             batch).compile()
+                    out["compile_s"] = time.perf_counter() - t
+                    out["memory"] = step_fn.memory_analysis()
+                    out["first_batch"] = batch
+                    print(f"compile: train step {out['compile_s']:.2f} s")
+                t = time.perf_counter()
                 params, opt_state, metrics = step_fn(params, opt_state, batch)
+                losses.append(float(metrics["loss"]))   # waits for the step
+                step_s.append(time.perf_counter() - t)
                 step += 1
-                losses.append(float(metrics["loss"]))
                 if step % args.log_every == 0 or step == until:
-                    print(f"step {step:5d} loss {losses[-1]:.4f}")
+                    print(f"step {step:5d} loss {losses[-1]:.4f} "
+                          f"({step_s[-1]:.3f} s)")
                 if step % args.checkpoint_every == 0:
+                    t = time.perf_counter()
                     cid = save_checkpoint(
                         dm, f"checkpoints/{cfg.name}", step, params, opt_state,
                         extra={"loader": loader_state},
                         data_snapshot_id=snap.snapshot_id, run_node=run_node)
-                    print(f"  checkpointed step {step} -> version {cid[:12]}")
+                    save_s.append(time.perf_counter() - t)
+                    print(f"  checkpointed step {step} -> version {cid[:12]} "
+                          f"({save_s[-1]:.2f} s)")
         finally:
             feed_it.close()   # stop decode workers; buffered batches drop
 
     if args.kill_at and args.kill_at < args.steps:
         do_train(args.kill_at)
         print(f"--- simulated crash at step {step}; restarting ---")
+        # What the newest checkpoint holds, when it is of the crash step.
+        saved = (jax.device_get((params, opt_state))
+                 if latest_step(dm, f"checkpoints/{cfg.name}") == step
+                 else None)
+        del params, opt_state
         # Restart path: fresh process state, restore from the platform.
-        like_p = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
-        like_o = jax.eval_shape(opt.init, like_p)
+        t = time.perf_counter()
         params, opt_state, extra = load_checkpoint(
-            dm, f"checkpoints/{cfg.name}", like_p, like_o)
+            dm, f"checkpoints/{cfg.name}", like_p, like_o,
+            param_shardings=p_sh, opt_shardings=o_sh)
+        jax.block_until_ready((params, opt_state))
+        restore_s = time.perf_counter() - t
         loader.restore(extra["loader"])
         step = int(np.asarray(opt_state["step"]))
-        print(f"restored at step {step}, loader {extra['loader']}")
+        bit_equal = (None if saved is None
+                     else _tree_bit_equal(saved, (params, opt_state)))
+        del saved
+        out["restore"] = {"step": step, "seconds": restore_s,
+                          "bit_equal": bit_equal, "loader": extra["loader"]}
+        print(f"restored at step {step} in {restore_s:.2f} s, "
+              f"bit-equal to the saved state: {bit_equal}, "
+              f"loader {extra['loader']}")
 
     do_train(args.steps)
 
@@ -197,14 +291,14 @@ def main(argv=None) -> dict:
     print(f"loss: first5={first:.4f} last5={last:.4f} "
           f"({'improved' if last < first else 'NOT improved'})")
     # lineage: the checkpoint's provenance reaches the raw corpus
-    from ..train.checkpoint import checkpoint_node_id
-
     anc = dm.lineage.ancestors(checkpoint_node_id(f"checkpoints/{cfg.name}",
                                                   step))
     print(f"lineage ancestors of final checkpoint: {len(anc)} node(s)")
-    return {"losses": losses, "steps": step, "dm": dm, "platform": plat,
-            "checkpoint": cid, "improved": bool(last < first),
-            "loader": loader, "loader_stats": ld_stats}
+    out.update({"losses": losses, "step_s": step_s, "save_s": save_s,
+                "steps": step, "dm": dm, "platform": plat, "checkpoint": cid,
+                "improved": bool(last < first), "loader": loader,
+                "loader_stats": ld_stats})
+    return out
 
 
 if __name__ == "__main__":

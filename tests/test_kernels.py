@@ -150,6 +150,22 @@ def test_ssd_zero_initial_state(impl):
     _assert_close(sf, sf_ref, jnp.float32)
 
 
+def test_ssd_xla_grad_finite_under_strong_decay():
+    """A 256-long chunk with strong decay: exp(la_t - la_r) above the
+    diagonal overflows, and must not turn the gradient into NaN."""
+    x, _, Bm, Cm, s0 = _ssd_inputs(jax.random.PRNGKey(7), 1, 256, 2, 8, 8)
+    a = jnp.full((1, 256, 2), 0.3, jnp.float32)
+
+    def loss(x, a):
+        return jnp.sum(ssd(x, a, Bm, Cm, s0, chunk=256, impl="xla")[0] ** 2)
+
+    gx, ga = jax.grad(loss, argnums=(0, 1))(x, a)
+    assert np.isfinite(np.asarray(gx)).all()
+    assert np.isfinite(np.asarray(ga)).all()
+    y, _ = ssd(x, a, Bm, Cm, s0, chunk=256, impl="xla")
+    _assert_close(y, ssd_reference(x, a, Bm, Cm, s0)[0], jnp.float32)
+
+
 def test_ssd_decode_chain_equals_scan():
     x, a, Bm, Cm, s0 = _ssd_inputs(jax.random.PRNGKey(2), 2, 16, 4, 16, 32)
     state = s0

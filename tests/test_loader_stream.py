@@ -8,7 +8,6 @@ checkpoint contract, so any drift must fail loudly.
 """
 
 import hashlib
-import io
 import threading
 import time
 
@@ -17,6 +16,7 @@ import pytest
 
 from repro.core import Record
 from repro.data import DeviceFeed, ShardedSnapshotLoader
+from repro.data.components import encode_packed
 from repro.data.loader import _PAGE_SURFACE, _order_fast, _page_perm
 from repro.platform import Platform
 
@@ -27,8 +27,8 @@ BATCH = 8
 PER_EPOCH = N // BATCH
 
 # -- golden constants (generated once from this fixture, then frozen) -------
-GOLDEN_PAGES_DIGEST = (
-    "3f3228df8dcd679ee7cec90b253471f4ee6dec9b23075ff59e92c75827e4f043")
+GOLDEN_PAGES_DIGEST = (       # over RPK1 payloads (numpy-version independent)
+    "bf14c68e72582ca095a02f1527105377093fab1033e13dbee2ad85e60b541573")
 GOLDEN_PW_FIRST = (
     "e70a9699235ef74bae5ea2c8ae3d5f567fa71baff521fce9bd09aab980736f65")
 GOLDEN_PW_LAST_E0 = (
@@ -44,9 +44,8 @@ def _packed_record(i: int, seq_len: int = 16) -> Record:
     segments = np.zeros(L, np.int32)
     segments[-3:] = -1
     positions = np.arange(L, dtype=np.int32)
-    buf = io.BytesIO()
-    np.savez(buf, tokens=tokens, segments=segments, positions=positions)
-    return Record(f"rec-{i:05d}", buf.getvalue(), {"format": "packed.npz"})
+    return Record(f"rec-{i:05d}", encode_packed(tokens, segments, positions),
+                  {"format": "packed.bin"})
 
 
 def _batch_digest(batch) -> str:

@@ -13,9 +13,8 @@ from repro.train.sharding import ActivationSharding, ShardingRules
 
 
 def _mesh11():
-    from repro.launch.mesh import _auto_kwargs
-
-    return jax.make_mesh((1, 1), ("data", "model"), **_auto_kwargs(2))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def test_shardmap_moe_matches_gspmd_path():

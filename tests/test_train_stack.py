@@ -140,8 +140,8 @@ def test_elastic_restore_onto_mesh():
     dm = DatasetManager(ObjectStore(MemoryBackend()))
     params = {"w": jnp.arange(16, dtype=jnp.float32).reshape(4, 4)}
     save_checkpoint(dm, "ckpt/elastic", 1, params)
-    from repro.launch.mesh import _auto_kwargs
-    mesh = jax.make_mesh((1,), ("data",), **_auto_kwargs(1))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     sh = {"w": NamedSharding(mesh, P("data", None))}
     p2, _, _ = load_checkpoint(dm, "ckpt/elastic",
                                jax.eval_shape(lambda: params),
