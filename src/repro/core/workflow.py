@@ -34,6 +34,7 @@ import uuid
 from dataclasses import dataclass, field
 from typing import Dict, List, Mapping, Optional, Tuple
 
+from .. import obs
 from .dataset import DatasetManager, Record
 from .derive import DerivationEngine, ExecPolicy, ShardReport
 from .lineage import EdgeKind, NodeKind
@@ -217,7 +218,8 @@ class WorkflowManager:
         run = WorkflowRun(run_id=f"run-{uuid.uuid4().hex[:12]}",
                           workflow=wf.name, trigger=trigger)
         self._runs[run.run_id] = run
-        self._execute(wf, run)
+        with obs.span("workflow.run"):
+            self._execute(wf, run)
         return run
 
     def resume(self, run_id: str) -> WorkflowRun:

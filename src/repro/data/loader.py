@@ -65,6 +65,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..core.dataset import CheckoutPlan, Snapshot
 from .components import decode_packed
 
@@ -380,24 +381,26 @@ class ShardedSnapshotLoader:
         positions = [base + self.shard_id + j * self.n_shards
                      for j in range(self.local_batch)]
         t0 = time.perf_counter()
-        if self._mode == "page_window":
-            entries = self._stream_entries(epoch, positions)
-            payloads = self.snapshot.read_entries(entries)
-            t1 = time.perf_counter()
-            rows = [self._decode_row(buf) for buf in payloads]
-        else:
-            order = self._epoch_order(epoch)
-            rids = [order[p] for p in positions]
-            t1 = time.perf_counter()
-            rows = self._read_rows(rids)
+        with obs.span("loader.read"):
+            if self._mode == "page_window":
+                entries = self._stream_entries(epoch, positions)
+                payloads = self.snapshot.read_entries(entries)
+            else:
+                order = self._epoch_order(epoch)
+                rids = [order[p] for p in positions]
+        t1 = time.perf_counter()
+        with obs.span("loader.decode"):
+            if self._mode == "page_window":
+                rows = [self._decode_row(buf) for buf in payloads]
+            else:
+                rows = self._read_rows(rids)
+            out = {k: np.stack([r[k] for r in rows]) for k in rows[0]}
+            # mask labels at padding (segment -1)
+            out["labels"] = np.where(out["segments"] >= 0, out["labels"], -1)
         t2 = time.perf_counter()
-        out = {k: np.stack([r[k] for r in rows]) for k in rows[0]}
-        # mask labels at padding (segment -1)
-        out["labels"] = np.where(out["segments"] >= 0, out["labels"], -1)
-        t3 = time.perf_counter()
         with self._lock:
             self._stats["read_time_s"] += t1 - t0
-            self._stats["decode_time_s"] += (t2 - t1) + (t3 - t2)
+            self._stats["decode_time_s"] += t2 - t1
         return out
 
     def _note_delivered(self, gstep: int) -> None:
@@ -436,7 +439,8 @@ class ShardedSnapshotLoader:
                 gstep, fut = pending.popleft()
                 t0 = time.perf_counter()
                 try:
-                    batch = fut.result(timeout=self.timeout_s)
+                    with obs.span("loader.wait"):
+                        batch = fut.result(timeout=self.timeout_s)
                 except (TimeoutError, cf.TimeoutError):
                     if fut.done():   # the batch itself raised TimeoutError
                         raise
@@ -520,21 +524,21 @@ class DeviceFeed:
         self.donate = donate
         self._shardings = shardings
         self._sharding_fn = sharding_fn
-        self._stats = {"transfers": 0, "put_dispatch_s": 0.0}
+        self._stats = {"transfers": 0}
 
     def _put(self, host_batch):
-        t0 = time.perf_counter()
         if self._shardings is None and self._sharding_fn is not None:
             self._shardings = self._sharding_fn(host_batch)
-        if self._shardings is None:
-            out = jax.device_put(host_batch)
-        else:
-            donate = (jax.tree.map(lambda x: isinstance(x, jax.Array),
-                                   host_batch)
-                      if self.donate else False)
-            out = jax.device_put(host_batch, self._shardings, donate=donate)
+        with obs.span("feed.put"):
+            if self._shardings is None:
+                out = jax.device_put(host_batch)
+            else:
+                donate = (jax.tree.map(lambda x: isinstance(x, jax.Array),
+                                       host_batch)
+                          if self.donate else False)
+                out = jax.device_put(host_batch, self._shardings,
+                                     donate=donate)
         self._stats["transfers"] += 1
-        self._stats["put_dispatch_s"] += time.perf_counter() - t0
         return out
 
     def __iter__(self):

@@ -153,8 +153,12 @@ def _flash_xla(
             jnp.zeros((B, Hq, bq), jnp.float32),
             jnp.zeros((B, Hq, bq, D), jnp.float32),
         )
-        (m, l, acc), _ = jax.lax.scan(
-            kv_step, init, (jnp.arange(n_k), kf, vf, ksb))
+        # The model's scope again: under remat JAX drops the caller's name
+        # stack from this scan's own slicing of the kv blocks, and the
+        # scope is what attributes those ops to the attention.
+        with jax.named_scope("attn_core"):
+            (m, l, acc), _ = jax.lax.scan(
+                kv_step, init, (jnp.arange(n_k), kf, vf, ksb))
         l_safe = jnp.where(l == 0.0, 1.0, l)
         out = (acc / l_safe[..., None]).transpose(0, 2, 1, 3)   # (B,bq,Hq,D)
         return out.astype(q.dtype)

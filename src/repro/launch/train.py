@@ -20,7 +20,7 @@ so it names XLA implementations: the Pallas kernels have no backward.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.train --arch mamba2-1.3b \
-        --steps 50 --smoke
+        --steps 50 --smoke [--trace-out spans.json]
 """
 
 from __future__ import annotations
@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..configs import get_config, get_smoke_config
 from ..core import Pipeline, Record, Workflow
 from ..core.lineage import NodeKind
@@ -120,6 +121,9 @@ def build_parser() -> argparse.ArgumentParser:
                          "above the size threshold, else legacy global)")
     ap.add_argument("--window-pages", type=int, default=8,
                     help="page-window shuffle width (pages per window)")
+    ap.add_argument("--trace-out", default=None, metavar="PATH",
+                    help="record the program's spans (repro.obs) and write "
+                         "them to PATH as Chrome trace events at exit")
     return ap
 
 
@@ -129,7 +133,13 @@ def main(argv=None) -> dict:
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     rt = RuntimeConfig(compute_dtype=jnp.float32, attn_impl="naive",
                        ssd_impl="xla", rglru_impl="xla")
-    return train(cfg, args, rt)
+    if not args.trace_out:
+        return train(cfg, args, rt)
+    obs.enable()
+    try:
+        return train(cfg, args, rt)
+    finally:
+        print(f"spans: {len(obs.spans())} -> {obs.export(args.trace_out)}")
 
 
 def _tree_bit_equal(a, b) -> bool:
@@ -230,8 +240,11 @@ def train(cfg, args: argparse.Namespace, rt: RuntimeConfig,
                     out["first_batch"] = batch
                     print(f"compile: train step {out['compile_s']:.2f} s")
                 t = time.perf_counter()
-                params, opt_state, metrics = step_fn(params, opt_state, batch)
-                losses.append(float(metrics["loss"]))   # waits for the step
+                with obs.span("train.dispatch"):
+                    params, opt_state, metrics = step_fn(params, opt_state,
+                                                         batch)
+                with obs.span("train.loss_sync"):
+                    losses.append(float(metrics["loss"]))   # waits for it
                 step_s.append(time.perf_counter() - t)
                 step += 1
                 if step % args.log_every == 0 or step == until:
@@ -239,10 +252,12 @@ def train(cfg, args: argparse.Namespace, rt: RuntimeConfig,
                           f"({step_s[-1]:.3f} s)")
                 if step % args.checkpoint_every == 0:
                     t = time.perf_counter()
-                    cid = save_checkpoint(
-                        dm, f"checkpoints/{cfg.name}", step, params, opt_state,
-                        extra={"loader": loader_state},
-                        data_snapshot_id=snap.snapshot_id, run_node=run_node)
+                    with obs.span("train.save"):
+                        cid = save_checkpoint(
+                            dm, f"checkpoints/{cfg.name}", step, params,
+                            opt_state, extra={"loader": loader_state},
+                            data_snapshot_id=snap.snapshot_id,
+                            run_node=run_node)
                     save_s.append(time.perf_counter() - t)
                     print(f"  checkpointed step {step} -> version {cid[:12]} "
                           f"({save_s[-1]:.2f} s)")
@@ -277,10 +292,11 @@ def train(cfg, args: argparse.Namespace, rt: RuntimeConfig,
 
     do_train(args.steps)
 
-    cid = save_checkpoint(dm, f"checkpoints/{cfg.name}", step, params,
-                          opt_state, extra={"loader": loader.state()},
-                          data_snapshot_id=snap.snapshot_id,
-                          run_node=run_node)
+    with obs.span("train.save"):
+        cid = save_checkpoint(dm, f"checkpoints/{cfg.name}", step, params,
+                              opt_state, extra={"loader": loader.state()},
+                              data_snapshot_id=snap.snapshot_id,
+                              run_node=run_node)
     print(f"final checkpoint -> {cid[:12]}")
     ld_stats = loader.stats()
     print(f"loader: mode={ld_stats['mode']} "

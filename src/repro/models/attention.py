@@ -57,26 +57,29 @@ def attn_apply(
     B, S, _ = x.shape
     Hq, Hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     src = x if kv_x is None else kv_x
-    q = rt.heads_constraint(_project(params["wq"], x, Hq, dh))
-    k = rt.heads_constraint(_project(params["wk"], src, Hkv, dh))
-    v = rt.heads_constraint(_project(params["wv"], src, Hkv, dh))
-    if use_rope and kv_x is None:
-        if positions is None:
-            positions = jnp.arange(S)
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
-    out = flash_attention(
-        q, k, v,
-        causal=causal and kv_x is None,
-        window=window,
-        softcap=cfg.attn_softcap,
-        q_segments=segments,
-        kv_segments=segments if kv_x is None else None,
-        impl=rt.attn_impl,
-        block_q=rt.attn_block_q,
-        block_k=rt.attn_block_k,
-    )
-    y = out.reshape(B, S, Hq * dh) @ params["wo"]["w"].astype(x.dtype)
+    with jax.named_scope("attn_proj"):
+        q = rt.heads_constraint(_project(params["wq"], x, Hq, dh))
+        k = rt.heads_constraint(_project(params["wk"], src, Hkv, dh))
+        v = rt.heads_constraint(_project(params["wv"], src, Hkv, dh))
+        if use_rope and kv_x is None:
+            if positions is None:
+                positions = jnp.arange(S)
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("attn_core"):
+        out = flash_attention(
+            q, k, v,
+            causal=causal and kv_x is None,
+            window=window,
+            softcap=cfg.attn_softcap,
+            q_segments=segments,
+            kv_segments=segments if kv_x is None else None,
+            impl=rt.attn_impl,
+            block_q=rt.attn_block_q,
+            block_k=rt.attn_block_k,
+        )
+    with jax.named_scope("attn_proj"):
+        y = out.reshape(B, S, Hq * dh) @ params["wo"]["w"].astype(x.dtype)
     if return_kv:
         return y, (k, v)
     return y

@@ -123,9 +123,11 @@ class DecoderLM:
         cfg, rt = self.cfg, self.rt
         h = norm_apply(p["norm1"], x, cfg.norm)
         if kind == "ssm":
-            return x + ssm_apply(p["ssm"], h, cfg, rt)
+            with jax.named_scope("ssm"):
+                return x + ssm_apply(p["ssm"], h, cfg, rt)
         if kind == "rec":
-            mix = rec_apply(p["rec"], h, cfg, rt)
+            with jax.named_scope("rec"):
+                mix = rec_apply(p["rec"], h, cfg, rt)
         else:
             mix = attn_apply(
                 p["attn"], h, cfg, rt, positions=positions,
@@ -135,18 +137,20 @@ class DecoderLM:
             mix = norm_apply(p["post_norm1"], mix, cfg.norm)
         x = x + mix
         h2 = norm_apply(p["norm2"], x, cfg.norm)
-        if cfg.n_experts:
-            moe_fn = (moe_apply_shardmap if rt.moe_impl == "shard_map"
-                      else moe_apply)
-            y, _aux = moe_fn(p["moe"], h2, cfg, rt)
-            if cfg.dense_residual:
-                y = y + mlp_apply(p["mlp"], h2, cfg.act)
-        else:
-            y = mlp_apply(p["mlp"], h2, cfg.act)
+        with jax.named_scope("mlp"):
+            if cfg.n_experts:
+                moe_fn = (moe_apply_shardmap if rt.moe_impl == "shard_map"
+                          else moe_apply)
+                y, _aux = moe_fn(p["moe"], h2, cfg, rt)
+                if cfg.dense_residual:
+                    y = y + mlp_apply(p["mlp"], h2, cfg.act)
+            else:
+                y = mlp_apply(p["mlp"], h2, cfg.act)
         if cfg.post_norms:
             y = norm_apply(p["post_norm2"], y, cfg.norm)
         return self.rt.hidden(x + y)
 
+    @jax.named_scope("embed")
     def _embed(self, params, tokens, frontend_embeds):
         cfg = self.cfg
         x = params["embed"].astype(self.rt.compute_dtype)[tokens]
@@ -157,6 +161,7 @@ class DecoderLM:
             x = jnp.concatenate([fe, x], axis=1)
         return self.rt.hidden(x)
 
+    @jax.named_scope("layers")
     def _trunk(self, params, x, *, positions, segments):
         """Scanned superblocks + tail."""
 
@@ -196,6 +201,10 @@ class DecoderLM:
 
     def forward(self, params, batch: Dict[str, jnp.ndarray]) -> jnp.ndarray:
         """Training/eval forward -> fp32 logits (B, S_total, V_pad)."""
+        return self._logits(params, self._hidden(params, batch))
+
+    def _hidden(self, params, batch: Dict[str, jnp.ndarray]) -> jnp.ndarray:
+        """Embedding and trunk -> the last layer's output (B, S_total, D)."""
         tokens = batch["tokens"]
         B, S_text = tokens.shape
         positions = batch.get("positions")
@@ -205,17 +214,18 @@ class DecoderLM:
         S_total = x.shape[1]
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(S_total), (B, S_total))
-        x = self._trunk(params, x, positions=positions, segments=segments)
-        return self._logits(params, x)
+        return self._trunk(params, x, positions=positions, segments=segments)
 
     def loss(self, params, batch) -> Tuple[jnp.ndarray, Dict]:
         """Next-token cross entropy; labels < 0 are masked."""
-        logits = self.forward(params, batch)
-        labels = batch["labels"]
-        # frontend prefix positions produce logits we do not supervise
-        S_text = labels.shape[1]
-        logits = logits[:, -S_text:, :]
-        return xent_loss(logits, labels)
+        x = self._hidden(params, batch)
+        with jax.named_scope("head_loss"):
+            logits = self._logits(params, x)
+            labels = batch["labels"]
+            # frontend prefix positions produce logits we do not supervise
+            S_text = labels.shape[1]
+            logits = logits[:, -S_text:, :]
+            return xent_loss(logits, labels)
 
     # ------------------------------------------------------------------ serve
 

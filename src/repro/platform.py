@@ -25,6 +25,7 @@ from __future__ import annotations
 import os
 from typing import Iterable, List, Mapping, Optional, Sequence, Tuple, Union
 
+from . import obs
 from .core.acl import AccessController
 from .core.dataset import (CheckoutPlan, DatasetManager, Record, Snapshot,
                            version_node_id)
@@ -283,8 +284,9 @@ class DatasetHandle:
         actor: Optional[str] = None,
         **kwargs,
     ) -> Commit:
-        return self._dm.check_in(self.name, records, self._actor(actor),
-                                 message=message, **kwargs)
+        with obs.span("platform.check_in"):
+            return self._dm.check_in(self.name, records, self._actor(actor),
+                                     message=message, **kwargs)
 
     def delete_records(self, record_ids: Sequence[str],
                        actor: Optional[str] = None,
@@ -317,10 +319,11 @@ class DatasetHandle:
         ``use_index=False`` forces the full-scan path (identical results;
         exists for benchmarking and as an escape hatch).
         """
-        return self._dm.plan_checkout(self.name, self._actor(actor), rev=rev,
-                                      where=where, attrs_equal=attrs_equal,
-                                      limit=limit, shard=shard,
-                                      use_index=use_index)
+        with obs.span("dataset.plan"):
+            return self._dm.plan_checkout(
+                self.name, self._actor(actor), rev=rev, where=where,
+                attrs_equal=attrs_equal, limit=limit, shard=shard,
+                use_index=use_index)
 
     def index_stats(self, rev: str = "main",
                     actor: Optional[str] = None) -> Optional[dict]:

@@ -73,8 +73,9 @@ def make_train_step(model, train_cfg: TrainConfig) -> Callable:
             loss = loss_sum / n_micro
             aux = {"loss": loss}
 
-        grads, gnorm = clip_by_norm(grads, train_cfg.optimizer.grad_clip)
-        params, opt_state = opt.update(grads, opt_state, params)
+        with jax.named_scope("optimizer"):
+            grads, gnorm = clip_by_norm(grads, train_cfg.optimizer.grad_clip)
+            params, opt_state = opt.update(grads, opt_state, params)
         metrics = {"loss": loss, "grad_norm": gnorm,
                    "step": opt_state["step"]}
         return params, opt_state, metrics
