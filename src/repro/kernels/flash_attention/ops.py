@@ -3,8 +3,8 @@
 - ``impl="pallas"``: the TPU kernel; raises off the TPU.
 - ``impl="pallas_interpret"``: the same kernel in interpret mode (CPU tests).
 - ``impl="xla"``: memory-efficient chunked flash in pure jnp (nested scans,
-  online softmax) — used for dry-run lowering on CPU and as a safe fallback;
-  never materializes (Sq, Sk).
+  online softmax) with its own backward (recomputed score blocks) — the
+  differentiated path of the train step; never materializes (Sq, Sk).
 - ``impl="naive"``: the oracle (small shapes / decode single-token).
 - ``impl="auto"``: pallas on TPU, xla for long sequences elsewhere, naive
   when the score matrix is small.
@@ -13,7 +13,7 @@
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -78,10 +78,10 @@ def _flash_xla(
     scale, block_q, block_k,
 ):
     """Chunked online-softmax attention in pure jnp (scan over q and kv
-    blocks).  Transient memory is O(bq * bk) per (B, H) — never (Sq, Sk)."""
+    blocks).  Transient memory is O(bq * bk) per (B, H) — never (Sq, Sk),
+    in the backward too (``_chunked_bwd``)."""
     B, Sq, Hq, D = q.shape
     _, Sk, Hkv, _ = k.shape
-    group = Hq // Hkv
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     bq = min(block_q, Sq)
@@ -104,6 +104,48 @@ def _flash_xla(
             kv_segments=kv_segments if use_segments else None,
             q_offset=q_offset, scale=scale)
 
+    spec = _Blocks(causal, window, softcap, q_offset, scale, bq, bk,
+                   use_segments)
+    return _chunked(spec, q, k, v, q_segments, kv_segments)
+
+
+class _Blocks(NamedTuple):
+    """The static side of a chunked attention call."""
+    causal: bool
+    window: Optional[int]
+    softcap: Optional[float]
+    q_offset: int
+    scale: float
+    bq: int
+    bk: int
+    use_segments: bool
+
+    def mask(self, qi, ki, qs_blk, ks_blk):
+        """Which keys of kv block ``ki`` the queries of q block ``qi`` see:
+        bool (B or 1, bq, bk)."""
+        q_pos = self.q_offset + qi * self.bq + jnp.arange(self.bq)
+        k_pos = ki * self.bk + jnp.arange(self.bk)
+        mask = jnp.ones((self.bq, self.bk), bool)
+        if self.causal:
+            mask &= q_pos[:, None] >= k_pos[None, :]
+        if self.window is not None:
+            mask &= (q_pos[:, None] - k_pos[None, :]) < self.window
+        mask = mask[None]
+        if self.use_segments:
+            mask = mask & (qs_blk[:, :, None] == ks_blk[:, None, :])
+        return mask
+
+
+def _chunked_forward(spec, q, k, v, q_segments, kv_segments):
+    """The online-softmax kv scan inside a map over q blocks.  Returns the
+    output in float32 by q block (n_q, B, Hq, bq, D) and each query row's
+    logsumexp (n_q, B, Hq, bq); a row that sees no key has logsumexp +inf."""
+    B, Sq, Hq, D = q.shape
+    _, Sk, Hkv, _ = k.shape
+    group = Hq // Hkv
+    bq, bk, scale, softcap = spec.bq, spec.bk, spec.scale, spec.softcap
+    n_q, n_k = Sq // bq, Sk // bk
+
     # (n_q, B, bq, Hq, D) / (n_k, B, bk, Hkv, D)
     qb = q.reshape(B, n_q, bq, Hq, D).transpose(1, 0, 2, 3, 4)
     kb = k.reshape(B, n_k, bk, Hkv, D).transpose(1, 0, 2, 3, 4)
@@ -125,17 +167,7 @@ def _flash_xla(
             s = jnp.einsum("bqhd,bkhd->bhqk", qf, k_rep)
             if softcap is not None:
                 s = softcap * jnp.tanh(s / softcap)
-            q_pos = q_offset + qi * bq + jnp.arange(bq)
-            k_pos = ki * bk + jnp.arange(bk)
-            mask = jnp.ones((bq, bk), bool)
-            if causal:
-                mask &= q_pos[:, None] >= k_pos[None, :]
-            if window is not None:
-                mask &= (q_pos[:, None] - k_pos[None, :]) < window
-            mask = mask[None, None]
-            if use_segments:
-                mask = mask & (qs_blk[:, None, :, None]
-                               == ks_blk[:, None, None, :])
+            mask = spec.mask(qi, ki, qs_blk, ks_blk)[:, None]
             s = jnp.where(mask, s, NEG_INF)
             m_cur = jnp.max(s, axis=-1)
             m_new = jnp.maximum(m_prev, m_cur)
@@ -160,9 +192,102 @@ def _flash_xla(
             (m, l, acc), _ = jax.lax.scan(
                 kv_step, init, (jnp.arange(n_k), kf, vf, ksb))
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        out = (acc / l_safe[..., None]).transpose(0, 2, 1, 3)   # (B,bq,Hq,D)
-        return out.astype(q.dtype)
+        lse = jnp.where(l == 0.0, jnp.inf, m + jnp.log(l_safe))
+        return acc / l_safe[..., None], lse                     # (B,Hq,bq,D)
 
-    outs = jax.lax.map(
-        lambda xs: q_block(*xs), (jnp.arange(n_q), qb, qsb))     # (n_q,B,bq,H,D)
-    return outs.transpose(1, 0, 2, 3, 4).reshape(B, Sq, Hq, D)
+    return jax.lax.map(lambda xs: q_block(*xs), (jnp.arange(n_q), qb, qsb))
+
+
+def _unblock(out, dtype):
+    """(n_q, B, Hq, bq, D) float32 -> (B, Sq, Hq, D) in ``dtype``."""
+    n_q, B, Hq, bq, D = out.shape
+    return out.transpose(1, 0, 3, 2, 4).reshape(B, n_q * bq, Hq, D).astype(
+        dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _chunked(spec, q, k, v, q_segments, kv_segments):
+    out, _ = _chunked_forward(spec, q, k, v, q_segments, kv_segments)
+    return _unblock(out, q.dtype)
+
+
+def _chunked_fwd(spec, q, k, v, q_segments, kv_segments):
+    out, lse = _chunked_forward(spec, q, k, v, q_segments, kv_segments)
+    return _unblock(out, q.dtype), (q, k, v, q_segments, kv_segments, out,
+                                    lse)
+
+
+def _chunked_bwd(spec, res, d_out):
+    """FlashAttention-2's backward (Dao 2023, Algorithm 2): each score block
+    is recomputed from q and k and the kept logsumexp, so nothing of shape
+    (..., bq, bk) outlives its loop iteration.  A scan over kv blocks carries
+    dq; inside it a scan over q blocks carries that kv block's dk and dv.
+    The q heads that share a kv head are stacked on the query axis, so every
+    product is a matmul batched over (B, Hkv) and dk, dv sum the group."""
+    q, k, v, q_segments, kv_segments, out, lse = res
+    B, Sq, Hq, D = q.shape
+    _, Sk, Hkv, _ = k.shape
+    g = Hq // Hkv
+    bq, bk, scale, softcap = spec.bq, spec.bk, spec.scale, spec.softcap
+    n_q, n_k = Sq // bq, Sk // bk
+
+    def q_blocks(x):        # (B, Sq, Hq, ...) -> (n_q, B, Hkv, g * bq, ...)
+        x = x.reshape(B, n_q, bq, Hkv, g, *x.shape[3:])
+        x = jnp.moveaxis(x, (1, 2), (0, 4))
+        return x.reshape(n_q, B, Hkv, g * bq, *x.shape[5:])
+
+    def kv_blocks(x):       # (B, Sk, Hkv, D) -> (n_k, B, bk, Hkv, D)
+        return jnp.moveaxis(x.reshape(B, n_k, bk, Hkv, D), 1, 0)
+
+    with jax.named_scope("attn_core"):
+        d_out = d_out.astype(jnp.float32)
+        qf = q_blocks(q.astype(jnp.float32) * scale)
+        dof = q_blocks(d_out)
+        # rowsum(dO * O), with O kept by q block as (n_q, B, Hq, bq, D).
+        delta = (dof * out.reshape(dof.shape)).sum(-1)
+        lse = lse.reshape(n_q, B, Hkv, g * bq)
+        qsb = jnp.moveaxis(q_segments.reshape(B, n_q, bq), 1, 0)
+        ksb = jnp.moveaxis(kv_segments.reshape(B, n_k, bk), 1, 0)
+        kf = kv_blocks(k.astype(jnp.float32))
+        vf = kv_blocks(v.astype(jnp.float32))
+
+        def kv_block(dq, inputs):
+            ki, k_blk, v_blk, ks_blk = inputs
+
+            def q_step(carry, inputs):
+                dk, dv = carry
+                qi, q_blk, do_blk, lse_blk, delta_blk, qs_blk = inputs
+                s = jnp.einsum("bhmd,bkhd->bhmk", q_blk, k_blk)
+                if softcap is not None:
+                    t = jnp.tanh(s / softcap)
+                    s = softcap * t
+                mask = spec.mask(qi, ki, qs_blk, ks_blk)[:, None, None]
+                p = jnp.where(
+                    mask, jnp.exp(s - lse_blk[..., None]).reshape(
+                        B, Hkv, g, bq, bk), 0.0).reshape(s.shape)
+                dp = jnp.einsum("bhmd,bkhd->bhmk", do_blk, v_blk)
+                ds = p * (dp - delta_blk[..., None])
+                if softcap is not None:
+                    ds = ds * (1.0 - t * t)
+                dq_blk = jnp.einsum("bhmk,bkhd->bhmd", ds, k_blk) * scale
+                dk = dk + jnp.einsum("bhmk,bhmd->bkhd", ds, q_blk)
+                dv = dv + jnp.einsum("bhmk,bhmd->bkhd", p, do_blk)
+                return (dk, dv), dq_blk
+
+            zeros = jnp.zeros((B, bk, Hkv, D), jnp.float32)
+            (dk, dv), dq_blks = jax.lax.scan(
+                q_step, (zeros, zeros),
+                (jnp.arange(n_q), qf, dof, lse, delta, qsb))
+            return dq + dq_blks, (dk, dv)
+
+        dq, (dk, dv) = jax.lax.scan(
+            kv_block, jnp.zeros_like(qf), (jnp.arange(n_k), kf, vf, ksb))
+        dq = jnp.moveaxis(dq.reshape(n_q, B, Hkv, g, bq, D), (0, 4), (1, 2))
+        dq = dq.reshape(B, Sq, Hq, D)
+        dk = jnp.moveaxis(dk, 0, 1).reshape(B, Sk, Hkv, D)
+        dv = jnp.moveaxis(dv, 0, 1).reshape(B, Sk, Hkv, D)
+    return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
+            None, None)
+
+
+_chunked.defvjp(_chunked_fwd, _chunked_bwd)

@@ -94,6 +94,78 @@ def test_flash_q_offset_decode_chunk(impl):
     _assert_close(out, ref, jnp.float32)
 
 
+_GRAD_CASES = {
+    # (B, Sq, Sk, Hq, Hkv, D, causal, window, softcap, segments, q_offset,
+    #  block_q, block_k, dtype)
+    "mha": (1, 128, 128, 4, 4, 32, True, None, None, False, 0, 64, 64,
+            jnp.float32),
+    "gqa": (2, 128, 128, 8, 2, 32, True, None, None, False, 0, 64, 64,
+            jnp.float32),
+    "mqa": (1, 256, 256, 4, 1, 64, True, None, None, False, 0, 64, 64,
+            jnp.float32),
+    "window": (2, 128, 128, 4, 2, 32, True, 64, None, False, 0, 64, 64,
+               jnp.float32),
+    "softcap": (1, 128, 128, 4, 2, 32, True, None, 30.0, False, 0, 64, 64,
+                jnp.float32),
+    "bidirectional": (1, 128, 128, 4, 2, 32, False, None, None, False, 0,
+                      64, 64, jnp.float32),
+    "window_softcap": (2, 128, 128, 4, 2, 32, True, 32, 50.0, False, 0, 64,
+                       64, jnp.float32),
+    "segments": (2, 256, 256, 4, 2, 32, True, None, None, True, 0, 64, 64,
+                 jnp.float32),
+    "q_offset": (1, 64, 256, 4, 2, 32, True, None, None, False, 128, 32, 64,
+                 jnp.float32),
+    "bf16": (2, 128, 128, 4, 2, 64, True, None, None, True, 0, 64, 64,
+             jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", list(_GRAD_CASES.values()),
+                         ids=list(_GRAD_CASES))
+def test_flash_xla_grad_matches_reference(case):
+    """The chunked path's own backward against autodiff of the oracle, with
+    several q and kv blocks so the block loops run."""
+    (B, Sq, Sk, Hq, Hkv, D, causal, window, softcap, segments, q_offset,
+     block_q, block_k, dtype) = case
+    q, k, v = _qkv(jax.random.PRNGKey(6), B, Sq, Sk, Hq, Hkv, D, dtype)
+    kw = dict(causal=causal, window=window, softcap=softcap,
+              q_offset=q_offset)
+    if segments:
+        segs = jnp.cumsum(
+            (jax.random.uniform(jax.random.PRNGKey(7), (B, Sq)) < 0.02),
+            axis=1).astype(jnp.int32)
+        kw.update(q_segments=segs, kv_segments=segs)
+    d_out = jax.random.normal(jax.random.PRNGKey(8), q.shape, jnp.float32)
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(
+            attn(q, k, v).astype(jnp.float32) * d_out)
+
+    grads = jax.jit(jax.grad(loss(lambda q, k, v: flash_attention(
+        q, k, v, impl="xla", block_q=block_q, block_k=block_k, **kw)),
+        (0, 1, 2)))(q, k, v)
+    refs = jax.jit(jax.grad(loss(lambda q, k, v: attention_reference(
+        q, k, v, **kw)), (0, 1, 2)))(q, k, v)
+    assert Sq // block_q > 1 and Sk // block_k > 1
+    for g, r in zip(grads, refs):
+        assert g.dtype == dtype
+        _assert_close(g, r, dtype)
+
+
+def test_flash_xla_vjp_keeps_no_score_block():
+    """What the forward keeps for the backward is q, k, v, the output and a
+    logsumexp per query row: no residual has a score block's B·Hq·bq·bk
+    elements (here more than q's), let alone a stack of them."""
+    B, S, Hq, Hkv, D, bq, bk = 1, 256, 4, 2, 16, 64, 128
+    q, k, v = _qkv(jax.random.PRNGKey(9), B, S, S, Hq, Hkv, D, jnp.float32)
+    segs = jnp.zeros((B, S), jnp.int32)
+    _, vjp = jax.vjp(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, q_segments=segs, kv_segments=segs,
+        impl="xla", block_q=bq, block_k=bk), q, k, v)
+    sizes = [x.size for x in jax.tree.leaves(vjp)]
+    assert sizes and max(sizes) < B * Hq * bq * bk, sizes
+
+
 @settings(max_examples=12, deadline=None)
 @given(
     b=st.integers(1, 2),

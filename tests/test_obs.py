@@ -261,3 +261,29 @@ def test_dynamic_slices_belong_to_the_layer_scan_or_a_block_scope():
         lead = 1 if m.group(3) == "dynamic-slice" else L
         assert dims[0] == lead and dims[1:] in per_layer, line
     assert n_inner and n_scan
+
+
+def test_score_block_products_carry_attn_core():
+    """Every matmul that yields a score block (B·H·bq·bk elements, batch
+    first, keys last; the compiler may merge the query axis with the head
+    group) carries ``attn_core``: the forward's, its remat recompute's and
+    the attention backward's own, which sits under the layer scan's
+    transpose outside the remat recompute."""
+    cfg = _tiny_attention()
+    B, bq, bk = 2, 16, 32                       # _step_hlo's batch and blocks
+    hlo, _ = _step_hlo(cfg, B)
+    backward = 0
+    for line in hlo.splitlines():
+        m = _INSTRUCTION.match(line)
+        if not m or m.group(3) not in ("dot", "convolution"):
+            continue
+        dims = tuple(int(d) for d in re.findall(r"\d+", m.group(2).split(
+            "[", 1)[1].split("]", 1)[0]))
+        if (len(dims) < 3 or dims[0] != B or dims[-1] != bk
+                or np.prod(dims) != B * cfg.n_heads * bq * bk):
+            continue
+        assert "attn_core" in _scopes(m.group(4)), line
+        if ("transpose(" in m.group(4)
+                and "rematted_computation" not in m.group(4)):
+            backward += 1
+    assert backward >= 2, "no score block of the backward found"
