@@ -57,15 +57,17 @@ def run() -> List[Tuple[str, float, str]]:
     B2, S2, H, P, N = 1, 2048, 8, 64, 64
     ks = jax.random.split(key, 5)
     x = jax.random.normal(ks[0], (B2, S2, H, P), jnp.float32)
-    a = jax.nn.sigmoid(jax.random.normal(ks[1], (B2, S2, H))) * 0.5 + 0.5
+    log_a = jnp.log(
+        jax.nn.sigmoid(jax.random.normal(ks[1], (B2, S2, H))) * 0.5 + 0.5)
     Bm = jax.random.normal(ks[2], (B2, S2, N)) * 0.3
     Cm = jax.random.normal(ks[3], (B2, S2, N)) * 0.3
     ssd_xla = jax.jit(lambda *args: ssd(*args, chunk=256, impl="xla")[0])
-    us = _time(ssd_xla, x, a, Bm, Cm)
+    us = _time(ssd_xla, x, log_a, Bm, Cm)
     rows.append((f"ssd_xla_s{S2}_chunk256", us,
                  f"{B2 * S2 / (us / 1e6) / 1e6:.2f}Mtok/s"))
-    y_ref, _ = ssd_reference(x[:, :256], a[:, :256], Bm[:, :256], Cm[:, :256])
-    y, _ = ssd(x[:, :256], a[:, :256], Bm[:, :256], Cm[:, :256],
+    y_ref, _ = ssd_reference(x[:, :256], log_a[:, :256], Bm[:, :256],
+                             Cm[:, :256])
+    y, _ = ssd(x[:, :256], log_a[:, :256], Bm[:, :256], Cm[:, :256],
                chunk=64, impl="pallas_interpret")
     rows.append(("ssd_pallas_interpret_maxerr",
                  float(jnp.abs(y - y_ref).max()), "vs oracle"))

@@ -220,11 +220,12 @@ def phase_kernels(tiny: bool, seed: int) -> None:
 
     # SSD at mamba2-1.3b widths: 64 heads of 64, state 128, chunk 256.
     H, P, N, chunk = (4, 16, 32, 32) if tiny else (64, 64, 128, 256)
-    a = jax.nn.sigmoid(normal((B, S, H), jnp.float32)) * 0.5 + 0.5
+    log_a = jnp.log(jax.nn.sigmoid(normal((B, S, H), jnp.float32)) * 0.5
+                    + 0.5)
     run("ssd", f"B{B} S{S} H{H} P{P} N{N} chunk{chunk}",
-        lambda x, a, b, c, s0: ssd(x, a, b, c, s0, chunk=chunk, impl=impl),
+        lambda x, la, b, c, s0: ssd(x, la, b, c, s0, chunk=chunk, impl=impl),
         ssd_reference,
-        (normal((B, S, H, P)), a, normal((B, S, N), scale=0.3),
+        (normal((B, S, H, P)), log_a, normal((B, S, N), scale=0.3),
          normal((B, S, N), scale=0.3),
          normal((B, H, P, N), jnp.float32, 0.1)))
 

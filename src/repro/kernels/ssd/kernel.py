@@ -12,8 +12,10 @@ is, within a chunk of length c, a *matmul*:
 so the TPU-native formulation is: grid (B, H, n_chunks) with the chunk
 dimension sequential ("arbitrary"), the running state (P, N) living in fp32
 VMEM scratch across chunk iterations, and both the intra-chunk (c x c)(c x P)
-and state (c x N)(N x P) products on the MXU.  All decay weights are <= 1
-(a in (0,1]) so the blocked form is numerically stable in fp32.
+and state (c x N)(N x P) products on the MXU.  The decay comes in as
+log_a <= 0 and is only cumsummed, never logged: every exponent the kernel
+keeps is <= 0, so the blocked form is stable in fp32 and a decay that
+underflows exp stays finite.
 """
 
 from __future__ import annotations
@@ -89,7 +91,7 @@ def _ssd_kernel(
 
 def ssd_pallas(
     x: jnp.ndarray,                     # (B, S, H, P)
-    a: jnp.ndarray,                     # (B, S, H)
+    log_a: jnp.ndarray,                 # (B, S, H) log decay, <= 0
     B_mat: jnp.ndarray,                 # (B, S, N)
     C_mat: jnp.ndarray,                 # (B, S, N)
     initial_state: jnp.ndarray,         # (B, H, P, N)
@@ -109,7 +111,7 @@ def ssd_pallas(
     # log-decay as (B, H, 1, S).
     xt = x.transpose(0, 2, 1, 3)
     la = jnp.cumsum(
-        jnp.log(a.astype(jnp.float32)).transpose(0, 2, 1).reshape(
+        log_a.astype(jnp.float32).transpose(0, 2, 1).reshape(
             Bsz, H, n_chunks, chunk), axis=-1).reshape(Bsz, H, 1, S)
 
     y, sfin = pl.pallas_call(

@@ -1,4 +1,10 @@
-"""Public SSD op with implementation dispatch (pallas / xla-chunked / ref)."""
+"""Public SSD op with implementation dispatch (pallas / xla-chunked / ref).
+
+Every implementation takes the decay as ``log_a`` (float32, <= 0), the log
+of the per-step, per-head factor: Mamba-2's ``-dt * exp(A_log)``.  The
+chunked forms cumsum it directly; nothing exponentiates it and takes the
+log again, so a decay strong enough to underflow ``exp`` stays finite.
+"""
 
 from __future__ import annotations
 
@@ -18,7 +24,7 @@ __all__ = ["ssd", "ssd_step"]
 
 def ssd(
     x: jnp.ndarray,                     # (B, S, H, P)
-    a: jnp.ndarray,                     # (B, S, H)
+    log_a: jnp.ndarray,                 # (B, S, H) log decay, <= 0
     B_mat: jnp.ndarray,                 # (B, S, N)
     C_mat: jnp.ndarray,                 # (B, S, N)
     initial_state: Optional[jnp.ndarray] = None,
@@ -34,24 +40,24 @@ def ssd(
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "xla"
     if impl == "ref":
-        return ssd_reference(x, a, B_mat, C_mat, initial_state)
+        return ssd_reference(x, log_a, B_mat, C_mat, initial_state)
     if impl in ("pallas", "pallas_interpret"):
         if impl == "pallas" and jax.default_backend() != "tpu":
             raise RuntimeError(_NO_TPU)
         return ssd_pallas(
-            x, a, B_mat, C_mat, initial_state, chunk=chunk,
+            x, log_a, B_mat, C_mat, initial_state, chunk=chunk,
             interpret=impl == "pallas_interpret")
     if impl == "xla":
-        return _ssd_xla(x, a, B_mat, C_mat, initial_state, chunk=chunk)
+        return _ssd_xla(x, log_a, B_mat, C_mat, initial_state, chunk=chunk)
     raise ValueError(f"unknown impl {impl!r}")
 
 
-def ssd_step(state, x_t, a_t, b_t, c_t):
+def ssd_step(state, x_t, log_a_t, b_t, c_t):
     """Single-token decode step (pure jnp; the op is tiny)."""
-    return ssd_step_reference(state, x_t, a_t, b_t, c_t)
+    return ssd_step_reference(state, x_t, log_a_t, b_t, c_t)
 
 
-def _ssd_xla(x, a, B_mat, C_mat, initial_state, *, chunk):
+def _ssd_xla(x, log_a, B_mat, C_mat, initial_state, *, chunk):
     """Blocked SSD in pure jnp: scan over chunks, matmuls within.
 
     Same math as the Pallas kernel; used for CPU dry-run lowering so the
@@ -65,11 +71,11 @@ def _ssd_xla(x, a, B_mat, C_mat, initial_state, *, chunk):
     n_chunks = S // chunk
 
     xf = x.astype(jnp.float32).reshape(Bsz, n_chunks, chunk, H, P)
-    af = a.astype(jnp.float32).reshape(Bsz, n_chunks, chunk, H)
     Bf = B_mat.astype(jnp.float32).reshape(Bsz, n_chunks, chunk, N)
     Cf = C_mat.astype(jnp.float32).reshape(Bsz, n_chunks, chunk, N)
 
-    la = jnp.cumsum(jnp.log(af), axis=2)                 # (B, nc, c, H)
+    la = jnp.cumsum(log_a.astype(jnp.float32).reshape(Bsz, n_chunks, chunk, H),
+                    axis=2)                              # (B, nc, c, H)
     total = la[:, :, -1, :]                              # (B, nc, H)
 
     # Intra-chunk, all chunks in parallel (they don't depend on the state).

@@ -2,11 +2,13 @@
 
 Sequential-over-time reference:
 
-    s_t = a_t * s_{t-1} + x_t (outer) B_t          s: (P, N) per (batch, head)
+    s_t = exp(log_a_t) * s_{t-1} + x_t (outer) B_t   s: (P, N) per (batch, head)
     y_t = s_t @ C_t
 
-with x: (B, S, H, P), a: (B, S, H) in (0, 1], B/C: (B, S, N) shared across
-heads (single SSD group, as in mamba2).
+with x: (B, S, H, P), log_a: (B, S, H) the log decay (float32, <= 0;
+Mamba-2's -dt * exp(A_log)), B/C: (B, S, N) shared across heads (single SSD
+group, as in mamba2).  A decay strong enough to underflow exp(log_a) to 0
+stays exact.
 """
 
 from __future__ import annotations
@@ -21,7 +23,7 @@ __all__ = ["ssd_reference", "ssd_step_reference"]
 
 def ssd_reference(
     x: jnp.ndarray,                     # (B, S, H, P)
-    a: jnp.ndarray,                     # (B, S, H)
+    log_a: jnp.ndarray,                 # (B, S, H)
     B_mat: jnp.ndarray,                 # (B, S, N)
     C_mat: jnp.ndarray,                 # (B, S, N)
     initial_state: Optional[jnp.ndarray] = None,   # (B, H, P, N)
@@ -30,7 +32,7 @@ def ssd_reference(
     Bsz, S, H, P = x.shape
     N = B_mat.shape[-1]
     xf = x.astype(jnp.float32)
-    af = a.astype(jnp.float32)
+    af = jnp.exp(log_a.astype(jnp.float32))
     Bf = B_mat.astype(jnp.float32)
     Cf = C_mat.astype(jnp.float32)
     s0 = (jnp.zeros((Bsz, H, P, N), jnp.float32) if initial_state is None
@@ -53,12 +55,13 @@ def ssd_reference(
 def ssd_step_reference(
     state: jnp.ndarray,                 # (B, H, P, N) f32
     x_t: jnp.ndarray,                   # (B, H, P)
-    a_t: jnp.ndarray,                   # (B, H)
+    log_a_t: jnp.ndarray,               # (B, H)
     b_t: jnp.ndarray,                   # (B, N)
     c_t: jnp.ndarray,                   # (B, N)
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Single decode step; returns (y_t: (B, H, P), new_state)."""
-    state = state * a_t[..., None, None].astype(jnp.float32) + jnp.einsum(
+    a_t = jnp.exp(log_a_t.astype(jnp.float32))
+    state = state * a_t[..., None, None] + jnp.einsum(
         "bhp,bn->bhpn", x_t.astype(jnp.float32), b_t.astype(jnp.float32))
     y_t = jnp.einsum("bhpn,bn->bhp", state, c_t.astype(jnp.float32))
     return y_t.astype(x_t.dtype), state

@@ -3,7 +3,9 @@
 Follows the mamba2 structure (arXiv:2405.21060): the input projection emits
 [z (gate, Din), x (Din), B (N), C (N), dt (H)]; a short depthwise causal
 conv smooths (x, B, C); the SSD scan runs per head with scalar decay
-a = exp(-dt * exp(A_log)); output is RMS-norm(y * silu(z)) -> out_proj.
+a = exp(-dt * exp(A_log)), passed in log space as log_a = -dt * exp(A_log) so
+that a decay which underflows exp stays exact; output is
+RMS-norm(y * silu(z)) -> out_proj.
 """
 
 from __future__ import annotations
@@ -58,11 +60,11 @@ def _conv_scan(conv_w, conv_b, xbc, conv_state=None):
 
 
 def _gates(params, cfg, dt_raw):
-    """dt in fp32; decay a = exp(-dt * exp(A_log))."""
+    """dt in fp32 and the log decay log_a = -dt * exp(A_log)."""
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
                          + params["dt_bias"][None, None, :])
-    a = jnp.exp(-dt * jnp.exp(params["A_log"])[None, None, :])
-    return dt, a
+    log_a = -dt * jnp.exp(params["A_log"])[None, None, :]
+    return dt, log_a
 
 
 def ssm_apply(params: Dict, x: jnp.ndarray, cfg: ModelConfig,
@@ -79,11 +81,13 @@ def ssm_apply(params: Dict, x: jnp.ndarray, cfg: ModelConfig,
                                  params["conv_b"].astype(x.dtype),
                                  xbc, conv_in_state)
     xs, Bm, Cm = jnp.split(xbc, [Din, Din + N], axis=-1)
-    dt, a = _gates(params, cfg, dt_raw)                   # (B,S,H)
+    dt, log_a = _gates(params, cfg, dt_raw)               # (B,S,H)
 
     xh = xs.reshape(B, S, H, P) * dt[..., None].astype(xs.dtype)
     s0 = initial["ssd"] if initial is not None else None
-    y, final = ssd(xh, a, Bm, Cm, s0, chunk=cfg.ssm_chunk, impl=rt.ssd_impl)
+    with jax.named_scope("ssd"):
+        y, final = ssd(xh, log_a, Bm, Cm, s0, chunk=cfg.ssm_chunk,
+                       impl=rt.ssd_impl)
     y = y + params["D_skip"].astype(jnp.float32)[None, None, :, None] \
         * xs.reshape(B, S, H, P).astype(jnp.float32)
     y = y.reshape(B, S, Din).astype(x.dtype)
@@ -113,9 +117,10 @@ def ssm_decode(params: Dict, x_t: jnp.ndarray, cache: Dict,
                                  params["conv_b"].astype(x_t.dtype),
                                  xbc, cache["conv"])
     xs, Bm, Cm = jnp.split(xbc, [Din, Din + N], axis=-1)
-    dt, a = _gates(params, cfg, dt_raw)                   # (B,1,H)
+    dt, log_a = _gates(params, cfg, dt_raw)               # (B,1,H)
     xh = (xs.reshape(B, 1, H, P) * dt[..., None].astype(xs.dtype))[:, 0]
-    y, new_state = ssd_step(cache["ssd"], xh, a[:, 0], Bm[:, 0], Cm[:, 0])
+    y, new_state = ssd_step(cache["ssd"], xh, log_a[:, 0], Bm[:, 0],
+                            Cm[:, 0])
     y = y[:, None] + params["D_skip"].astype(jnp.float32)[None, None, :, None] \
         * xs.reshape(B, 1, H, P).astype(jnp.float32)
     y = y.reshape(B, 1, Din).astype(x_t.dtype)

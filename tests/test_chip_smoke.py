@@ -52,7 +52,7 @@ def test_without_tpu_or_tiny_exits_nonzero(capsys, keep_cache_dir):
 @pytest.mark.parametrize("call", [
     lambda: flash_attention(*[jnp.zeros((1, 128, 2, 32))] * 3,
                             impl="pallas"),
-    lambda: ssd(jnp.zeros((1, 32, 2, 16)), jnp.ones((1, 32, 2)),
+    lambda: ssd(jnp.zeros((1, 32, 2, 16)), jnp.zeros((1, 32, 2)),
                 jnp.zeros((1, 32, 16)), jnp.zeros((1, 32, 16)),
                 chunk=16, impl="pallas"),
     lambda: rglru(*[jnp.zeros((1, 32, 128))] * 3, jnp.zeros((128,)),

@@ -194,13 +194,14 @@ def test_property_flash_shape_sweep(b, log_s, hkv, group, log_d, causal, dtype):
 
 
 def _ssd_inputs(key, B, S, H, P, N, dtype=jnp.float32):
+    """x, the log decay log(a) for a in [0.5, 1), B, C and a start state."""
     ks = jax.random.split(key, 5)
     x = jax.random.normal(ks[0], (B, S, H, P), jnp.float32).astype(dtype)
     a = (jax.nn.sigmoid(jax.random.normal(ks[1], (B, S, H))) * 0.5 + 0.5)
     Bm = (jax.random.normal(ks[2], (B, S, N), jnp.float32) * 0.3).astype(dtype)
     Cm = (jax.random.normal(ks[3], (B, S, N), jnp.float32) * 0.3).astype(dtype)
     s0 = jax.random.normal(ks[4], (B, H, P, N), jnp.float32) * 0.1
-    return x, a.astype(jnp.float32), Bm, Cm, s0
+    return x, jnp.log(a.astype(jnp.float32)), Bm, Cm, s0
 
 
 @pytest.mark.parametrize("impl", IMPLS)
@@ -226,7 +227,7 @@ def test_ssd_xla_grad_finite_under_strong_decay():
     """A 256-long chunk with strong decay: exp(la_t - la_r) above the
     diagonal overflows, and must not turn the gradient into NaN."""
     x, _, Bm, Cm, s0 = _ssd_inputs(jax.random.PRNGKey(7), 1, 256, 2, 8, 8)
-    a = jnp.full((1, 256, 2), 0.3, jnp.float32)
+    a = jnp.log(jnp.full((1, 256, 2), 0.3, jnp.float32))
 
     def loss(x, a):
         return jnp.sum(ssd(x, a, Bm, Cm, s0, chunk=256, impl="xla")[0] ** 2)
@@ -280,6 +281,136 @@ def test_property_ssd_shape_sweep(b, nc, chunk, h, p, n):
         assert y.shape == x.shape
         _assert_close(y, y_ref, jnp.float32)
         _assert_close(sf, sf_ref, jnp.float32)
+
+
+SSD_WITNESS_IMPLS = ["xla", "ref", "pallas_interpret"]
+
+
+def _witness_log_a(key, B, S, H):
+    """Log decays past exp's float32 underflow (about -87.3): moderate
+    decays, a few positions per head near -90 and one at -120."""
+    k1, k2, k3 = jax.random.split(key, 3)
+    log_a = -jax.random.uniform(k1, (B, S, H), minval=0.0, maxval=1.0)
+    strong = jax.random.uniform(k2, (B, S, H)) < 0.05
+    near_90 = -jax.random.uniform(k3, (B, S, H), minval=88.0, maxval=92.0)
+    log_a = jnp.where(strong, near_90, log_a)
+    return log_a.at[:, S // 3, 0].set(-120.0)
+
+
+def _ssd_witness(key, S=256, chunk=64):
+    x, _, Bm, Cm, s0 = _ssd_inputs(key, 1, S, 2, 8, 8)
+    log_a = _witness_log_a(jax.random.fold_in(key, 1), 1, S, 2)
+    # The witness is one: a = exp(log_a) underflows, so log(a) is not finite.
+    with np.errstate(divide="ignore"):
+        assert not np.isfinite(np.log(np.exp(np.asarray(log_a)))).all()
+    return x, log_a, Bm, Cm, s0, chunk
+
+
+@pytest.mark.parametrize("impl", SSD_WITNESS_IMPLS)
+def test_ssd_witness_underflowing_decay(impl):
+    """Decays that underflow exp: outputs and final state finite and equal
+    to the sequential reference's."""
+    x, log_a, Bm, Cm, s0, chunk = _ssd_witness(jax.random.PRNGKey(11))
+    y_ref, sf_ref = ssd_reference(x, log_a, Bm, Cm, s0)
+    y, sf = ssd(x, log_a, Bm, Cm, s0, chunk=chunk, impl=impl)
+    assert np.isfinite(np.asarray(y)).all()
+    assert np.isfinite(np.asarray(sf)).all()
+    _assert_close(y, y_ref, jnp.float32)
+    _assert_close(sf, sf_ref, jnp.float32)
+
+
+@pytest.mark.parametrize("impl", ["xla", "ref"])
+def test_ssd_witness_grads(impl):
+    """Gradients with respect to x, log_a, B and C at the witness: finite
+    and equal to autodiff of the sequential reference.  (The Pallas kernel
+    is forward-only; its witness case is the test above.)"""
+    x, log_a, Bm, Cm, s0, chunk = _ssd_witness(jax.random.PRNGKey(12))
+    g = jax.random.normal(jax.random.PRNGKey(13), x.shape)
+    gs = jax.random.normal(jax.random.PRNGKey(14), s0.shape)
+
+    def loss(fn):
+        def f(x, log_a, Bm, Cm):
+            y, sf = fn(x, log_a, Bm, Cm)
+            return jnp.sum(y * g) + jnp.sum(sf * gs)
+        return jax.grad(f, argnums=(0, 1, 2, 3))
+
+    got = loss(lambda *a: ssd(*a, s0, chunk=chunk, impl=impl))(
+        x, log_a, Bm, Cm)
+    want = loss(lambda *a: ssd_reference(*a, s0))(x, log_a, Bm, Cm)
+    for name, gv, wv in zip(("x", "log_a", "B", "C"), got, want):
+        assert np.isfinite(np.asarray(gv)).all(), name
+        _assert_close(gv, wv, jnp.float32)
+
+
+def _linear_decay_scan(x, a, Bm, Cm, s0):
+    """The recurrence with the decay a itself as input, in numpy float64:
+    s_t = a_t s_{t-1} + x_t B_t^T, y_t = s_t C_t."""
+    x, a, Bm, Cm, s = (np.asarray(v, np.float64) for v in (x, a, Bm, Cm, s0))
+    ys = []
+    for t in range(x.shape[1]):
+        s = s * a[:, t, :, None, None] + np.einsum(
+            "bhp,bn->bhpn", x[:, t], Bm[:, t])
+        ys.append(np.einsum("bhpn,bn->bhp", s, Cm[:, t]))
+    return np.stack(ys, 1), s
+
+
+@pytest.mark.parametrize("impl", SSD_WITNESS_IMPLS + ["step"])
+def test_ssd_log_decay_agrees_with_linear_decay(impl):
+    """For decays a in (0, 1], passing log(a) gives what the recurrence in
+    a gives: a = 1 (log 0), tiny and ordinary decays."""
+    x, _, Bm, Cm, s0 = _ssd_inputs(jax.random.PRNGKey(15), 2, 64, 3, 8, 16)
+    a = jax.random.uniform(jax.random.PRNGKey(16), (2, 64, 3),
+                           minval=0.05, maxval=1.0)
+    a = a.at[:, ::7, 0].set(1.0).at[:, 5::11, 1].set(1e-30)
+    a = a.at[:, :, 2].set(0.999)
+    y_want, sf_want = _linear_decay_scan(x, a, Bm, Cm, s0)
+    log_a = jnp.log(a)
+    if impl == "step":
+        state, ys = s0, []
+        for t in range(x.shape[1]):
+            y_t, state = ssd_step(state, x[:, t], log_a[:, t], Bm[:, t],
+                                  Cm[:, t])
+            ys.append(y_t)
+        y, sf = jnp.stack(ys, 1), state
+    else:
+        y, sf = ssd(x, log_a, Bm, Cm, s0, chunk=16, impl=impl)
+    _assert_close(y, y_want, jnp.float32)
+    _assert_close(sf, sf_want, jnp.float32)
+
+
+@pytest.mark.parametrize("ssd_impl", ["xla", "ref"])
+def test_ssm_apply_grads_finite_past_decay_underflow(ssd_impl):
+    """A whole Mamba-2 mixer whose dt_bias drives half the heads'
+    dt * exp(A_log) past 88, where exp(-dt * exp(A_log)) underflows: the
+    loss and the gradient of every parameter are finite."""
+    import dataclasses
+
+    from repro.configs import get_smoke_config
+    from repro.models import RuntimeConfig
+    from repro.models.common import Initializer
+    from repro.models.ssm_block import (_gates, _split_proj, ssm_apply,
+                                        ssm_init)
+
+    cfg = dataclasses.replace(get_smoke_config("mamba2-1.3b"), ssm_chunk=32)
+    rt = RuntimeConfig(compute_dtype=jnp.float32, ssd_impl=ssd_impl)
+    params = ssm_init(Initializer(jax.random.PRNGKey(21)), cfg, jnp.float32)
+    H = cfg.ssm_heads
+    strong = jnp.arange(H) % 2 == 0
+    params["dt_bias"] = jnp.where(strong, 100.0, 0.0)
+    params["A_log"] = jnp.where(strong, 0.0, params["A_log"])
+    x = jax.random.normal(jax.random.PRNGKey(22), (2, 64, cfg.d_model))
+
+    dt_raw = _split_proj(cfg, x @ params["in_proj"])[2]
+    rate = -np.asarray(_gates(params, cfg, dt_raw)[1])
+    assert rate[..., ::2].min() > 88.0       # exp(-rate) underflows there
+
+    def loss(p):
+        return jnp.mean(ssm_apply(p, x, cfg, rt) ** 2)
+
+    value, grads = jax.value_and_grad(loss)(params)
+    assert np.isfinite(float(value))
+    for name, g in grads.items():
+        assert np.isfinite(np.asarray(g)).all(), name
 
 
 # ---------------------------------------------------------------------------

@@ -287,3 +287,25 @@ def test_score_block_products_carry_attn_core():
                 and "rematted_computation" not in m.group(4)):
             backward += 1
     assert backward >= 2, "no score block of the backward found"
+
+
+def test_ssd_products_carry_ssm_ssd():
+    """Every matmul of the SSD scan carries ``ssm/ssd``, in the forward,
+    its remat recompute and the backward, so none is left to the layer
+    scan: the chunked products and the state pass's are batch-first of
+    rank 3 or more, where the projections around the scan are 2-D."""
+    B = 2
+    hlo, _ = _step_hlo(get_smoke_config("mamba2-1.3b"), B)
+    phases = set()
+    for line in hlo.splitlines():
+        m = _INSTRUCTION.match(line)
+        if not m or m.group(3) not in ("dot", "convolution"):
+            continue
+        dims = tuple(int(d) for d in re.findall(r"\d+", m.group(2).split(
+            "[", 1)[1].split("]", 1)[0]))
+        if len(dims) < 3 or dims[0] != B:
+            continue
+        assert "ssm/ssd" in "/".join(_scopes(m.group(4))), line
+        phases.add("remat" if "rematted_computation" in m.group(4) else
+                   "backward" if "transpose(" in m.group(4) else "forward")
+    assert phases == {"forward", "remat", "backward"}

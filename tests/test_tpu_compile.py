@@ -50,7 +50,7 @@ def test_ssd_compiles_at_mamba2_widths(one_chip):
     # mamba2-1.3b: d_inner 4096 = 64 heads of 64, state 128, chunk 256.
     H, P, N = 64, 64, 128
     text = _compiled_text(
-        lambda x, a, b, c, s0: ssd_pallas(x, a, b, c, s0, chunk=256),
+        lambda x, la, b, c, s0: ssd_pallas(x, la, b, c, s0, chunk=256),
         [((BATCH, SEQ, H, P), jnp.bfloat16), ((BATCH, SEQ, H), jnp.float32),
          ((BATCH, SEQ, N), jnp.bfloat16), ((BATCH, SEQ, N), jnp.bfloat16),
          ((BATCH, H, P, N), jnp.float32)], one_chip)
