@@ -2,9 +2,10 @@
 
 - ``impl="pallas"``: the TPU kernel; raises off the TPU.
 - ``impl="pallas_interpret"``: the same kernel in interpret mode (CPU tests).
-- ``impl="xla"``: memory-efficient chunked flash in pure jnp (nested scans,
-  online softmax) with its own backward (recomputed score blocks) — the
-  differentiated path of the train step; never materializes (Sq, Sk).
+- ``impl="xla"``: memory-efficient chunked flash in pure jnp (one scan over
+  the block pairs the causal and window rules leave live, online softmax)
+  with its own backward (recomputed score blocks) — the differentiated path
+  of the train step; never materializes (Sq, Sk).
 - ``impl="naive"``: the oracle (small shapes / decode single-token).
 - ``impl="auto"``: pallas on TPU, xla for long sequences elsewhere, naive
   when the score matrix is small.
@@ -17,7 +18,9 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from ... import obs
 from .kernel import flash_attention_pallas
 from .ref import attention_reference
 
@@ -77,9 +80,11 @@ def _flash_xla(
     q, k, v, *, causal, window, softcap, q_segments, kv_segments, q_offset,
     scale, block_q, block_k,
 ):
-    """Chunked online-softmax attention in pure jnp (scan over q and kv
-    blocks).  Transient memory is O(bq * bk) per (B, H) — never (Sq, Sk),
-    in the backward too (``_chunked_bwd``)."""
+    """Chunked online-softmax attention in pure jnp (one scan over the live
+    pairs of q and kv blocks).  Transient memory is O(bq * bk) per (B, H) —
+    never (Sq, Sk), in the backward too (``_chunked_bwd``).  With ``obs``
+    on, each trace counts the pairs run (``attn.pairs_live``) of the grid
+    (``attn.pairs_all``)."""
     B, Sq, Hq, D = q.shape
     _, Sk, Hkv, _ = k.shape
     if scale is None:
@@ -106,6 +111,8 @@ def _flash_xla(
 
     spec = _Blocks(causal, window, softcap, q_offset, scale, bq, bk,
                    use_segments)
+    obs.count("attn.pairs_live", len(spec.live_pairs(n_q, n_k)))
+    obs.count("attn.pairs_all", n_q * n_k)
     return _chunked(spec, q, k, v, q_segments, kv_segments)
 
 
@@ -120,26 +127,53 @@ class _Blocks(NamedTuple):
     bk: int
     use_segments: bool
 
+    def visible(self, d):
+        """The causal and window rule on ``d``, a query's position minus a
+        key's (a NumPy or jnp integer array): whether the query may see the
+        key.  Segments aside, this is the whole mask."""
+        ok = True
+        if self.causal:
+            ok = ok & (d >= 0)
+        if self.window is not None:
+            ok = ok & (d < self.window)
+        return ok
+
     def mask(self, qi, ki, qs_blk, ks_blk):
         """Which keys of kv block ``ki`` the queries of q block ``qi`` see:
         bool (B or 1, bq, bk)."""
         q_pos = self.q_offset + qi * self.bq + jnp.arange(self.bq)
         k_pos = ki * self.bk + jnp.arange(self.bk)
-        mask = jnp.ones((self.bq, self.bk), bool)
-        if self.causal:
-            mask &= q_pos[:, None] >= k_pos[None, :]
-        if self.window is not None:
-            mask &= (q_pos[:, None] - k_pos[None, :]) < self.window
+        mask = jnp.ones((self.bq, self.bk), bool) & self.visible(
+            q_pos[:, None] - k_pos[None, :])
         mask = mask[None]
         if self.use_segments:
             mask = mask & (qs_blk[:, :, None] == ks_blk[:, None, :])
         return mask
 
+    def live_pairs(self, n_q, n_k):
+        """The block pairs ``(qi, ki)`` in which some query may see some key,
+        q-major with kv ascending; every other pair is masked whole, whatever
+        the segments.  Within a pair the query minus key positions take every
+        integer from ``1 - bk`` to ``bq - 1`` past the pair's own offset."""
+        d = np.arange(1 - self.bk, self.bq)
+        return [(qi, ki) for qi in range(n_q) for ki in range(n_k)
+                if np.any(self.visible(
+                    self.q_offset + qi * self.bq - ki * self.bk + d))]
+
+
+def _pair_indices(pairs):
+    """[(qi, ki), ...] -> the int32 arrays of q and kv block indices."""
+    qi, ki = np.asarray(pairs, np.int32).reshape(-1, 2).T
+    return jnp.asarray(qi), jnp.asarray(ki)
+
 
 def _chunked_forward(spec, q, k, v, q_segments, kv_segments):
-    """The online-softmax kv scan inside a map over q blocks.  Returns the
-    output in float32 by q block (n_q, B, Hq, bq, D) and each query row's
-    logsumexp (n_q, B, Hq, bq); a row that sees no key has logsumexp +inf."""
+    """The online softmax in one scan over the live block pairs, q-major
+    with kv ascending; each q block's running max, sum and output sit in
+    its slot of a stack, which starts as a block that has seen no key.
+    Returns the output in float32 by q block (n_q, B, Hq, bq, D) and each
+    query row's logsumexp (n_q, B, Hq, bq); a row that sees no key has
+    output 0 and logsumexp +inf."""
     B, Sq, Hq, D = q.shape
     _, Sk, Hkv, _ = k.shape
     group = Hq // Hkv
@@ -156,46 +190,45 @@ def _chunked_forward(spec, q, k, v, q_segments, kv_segments):
     kf = kb.astype(jnp.float32)
     vf = vb.astype(jnp.float32)
 
-    def q_block(qi, q_blk, qs_blk):
-        qf = q_blk.astype(jnp.float32) * scale         # (B, bq, Hq, D)
+    def pair_step(carry, pair):
+        m_all, l_all, acc_all = carry
+        qi, ki = pair
+        qf = qb[qi].astype(jnp.float32) * scale          # (B, bq, Hq, D)
+        k_rep = jnp.repeat(kf[ki], group, axis=2)       # (B, bk, Hq, D)
+        v_rep = jnp.repeat(vf[ki], group, axis=2)
+        m_prev, l_prev, acc = m_all[qi], l_all[qi], acc_all[qi]
+        s = jnp.einsum("bqhd,bkhd->bhqk", qf, k_rep)
+        if softcap is not None:
+            s = softcap * jnp.tanh(s / softcap)
+        mask = spec.mask(qi, ki, qsb[qi], ksb[ki])[:, None]
+        s = jnp.where(mask, s, NEG_INF)
+        m_cur = jnp.max(s, axis=-1)
+        m_new = jnp.maximum(m_prev, m_cur)
+        m_safe = jnp.where(m_new <= NEG_INF * 0.5, 0.0, m_new)
+        p = jnp.where(mask, jnp.exp(s - m_safe[..., None]), 0.0)
+        alpha = jnp.where(m_prev <= NEG_INF * 0.5, 0.0,
+                          jnp.exp(m_prev - m_safe))
+        l_new = alpha * l_prev + p.sum(-1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "bhqk,bkhd->bhqd", p, v_rep)
+        put = jax.lax.dynamic_update_index_in_dim
+        return (put(m_all, m_new, qi, 0), put(l_all, l_new, qi, 0),
+                put(acc_all, acc, qi, 0)), None
 
-        def kv_step(carry, inputs):
-            m_prev, l_prev, acc = carry
-            ki, k_blk, v_blk, ks_blk = inputs
-            k_rep = jnp.repeat(k_blk, group, axis=2)    # (B, bk, Hq, D)
-            v_rep = jnp.repeat(v_blk, group, axis=2)
-            s = jnp.einsum("bqhd,bkhd->bhqk", qf, k_rep)
-            if softcap is not None:
-                s = softcap * jnp.tanh(s / softcap)
-            mask = spec.mask(qi, ki, qs_blk, ks_blk)[:, None]
-            s = jnp.where(mask, s, NEG_INF)
-            m_cur = jnp.max(s, axis=-1)
-            m_new = jnp.maximum(m_prev, m_cur)
-            m_safe = jnp.where(m_new <= NEG_INF * 0.5, 0.0, m_new)
-            p = jnp.where(mask, jnp.exp(s - m_safe[..., None]), 0.0)
-            alpha = jnp.where(m_prev <= NEG_INF * 0.5, 0.0,
-                              jnp.exp(m_prev - m_safe))
-            l_new = alpha * l_prev + p.sum(-1)
-            acc = acc * alpha[..., None] + jnp.einsum(
-                "bhqk,bkhd->bhqd", p, v_rep)
-            return (m_new, l_new, acc), None
-
-        init = (
-            jnp.full((B, Hq, bq), NEG_INF, jnp.float32),
-            jnp.zeros((B, Hq, bq), jnp.float32),
-            jnp.zeros((B, Hq, bq, D), jnp.float32),
-        )
-        # The model's scope again: under remat JAX drops the caller's name
-        # stack from this scan's own slicing of the kv blocks, and the
-        # scope is what attributes those ops to the attention.
-        with jax.named_scope("attn_core"):
-            (m, l, acc), _ = jax.lax.scan(
-                kv_step, init, (jnp.arange(n_k), kf, vf, ksb))
+    init = (
+        jnp.full((n_q, B, Hq, bq), NEG_INF, jnp.float32),
+        jnp.zeros((n_q, B, Hq, bq), jnp.float32),
+        jnp.zeros((n_q, B, Hq, bq, D), jnp.float32),
+    )
+    # The model's scope again: under remat JAX drops the caller's name
+    # stack from this scan's own slicing of the blocks, and the scope is
+    # what attributes those ops to the attention.
+    with jax.named_scope("attn_core"):
+        (m, l, acc), _ = jax.lax.scan(
+            pair_step, init, _pair_indices(spec.live_pairs(n_q, n_k)))
         l_safe = jnp.where(l == 0.0, 1.0, l)
         lse = jnp.where(l == 0.0, jnp.inf, m + jnp.log(l_safe))
-        return acc / l_safe[..., None], lse                     # (B,Hq,bq,D)
-
-    return jax.lax.map(lambda xs: q_block(*xs), (jnp.arange(n_q), qb, qsb))
+        return acc / l_safe[..., None], lse
 
 
 def _unblock(out, dtype):
@@ -220,10 +253,11 @@ def _chunked_fwd(spec, q, k, v, q_segments, kv_segments):
 def _chunked_bwd(spec, res, d_out):
     """FlashAttention-2's backward (Dao 2023, Algorithm 2): each score block
     is recomputed from q and k and the kept logsumexp, so nothing of shape
-    (..., bq, bk) outlives its loop iteration.  A scan over kv blocks carries
-    dq; inside it a scan over q blocks carries that kv block's dk and dv.
-    The q heads that share a kv head are stacked on the query axis, so every
-    product is a matmul batched over (B, Hkv) and dk, dv sum the group."""
+    (..., bq, bk) outlives its loop iteration.  One scan over the live block
+    pairs, kv-major with q ascending, adds each pair's dq into its q block's
+    slot and its dk, dv into its kv block's.  The q heads that share a kv
+    head are stacked on the query axis, so every product is a matmul
+    batched over (B, Hkv) and dk, dv sum the group."""
     q, k, v, q_segments, kv_segments, out, lse = res
     B, Sq, Hq, D = q.shape
     _, Sk, Hkv, _ = k.shape
@@ -251,37 +285,36 @@ def _chunked_bwd(spec, res, d_out):
         kf = kv_blocks(k.astype(jnp.float32))
         vf = kv_blocks(v.astype(jnp.float32))
 
-        def kv_block(dq, inputs):
-            ki, k_blk, v_blk, ks_blk = inputs
+        def pair_step(carry, pair):
+            dq, dk, dv = carry
+            qi, ki = pair
+            q_blk, do_blk = qf[qi], dof[qi]
+            lse_blk, delta_blk = lse[qi], delta[qi]
+            k_blk, v_blk = kf[ki], vf[ki]
+            s = jnp.einsum("bhmd,bkhd->bhmk", q_blk, k_blk)
+            if softcap is not None:
+                t = jnp.tanh(s / softcap)
+                s = softcap * t
+            mask = spec.mask(qi, ki, qsb[qi], ksb[ki])[:, None, None]
+            p = jnp.where(
+                mask, jnp.exp(s - lse_blk[..., None]).reshape(
+                    B, Hkv, g, bq, bk), 0.0).reshape(s.shape)
+            dp = jnp.einsum("bhmd,bkhd->bhmk", do_blk, v_blk)
+            ds = p * (dp - delta_blk[..., None])
+            if softcap is not None:
+                ds = ds * (1.0 - t * t)
+            dq_blk = jnp.einsum("bhmk,bkhd->bhmd", ds, k_blk) * scale
+            dk_blk = jnp.einsum("bhmk,bhmd->bkhd", ds, q_blk)
+            dv_blk = jnp.einsum("bhmk,bhmd->bkhd", p, do_blk)
+            put = jax.lax.dynamic_update_index_in_dim
+            return (put(dq, dq[qi] + dq_blk, qi, 0),
+                    put(dk, dk[ki] + dk_blk, ki, 0),
+                    put(dv, dv[ki] + dv_blk, ki, 0)), None
 
-            def q_step(carry, inputs):
-                dk, dv = carry
-                qi, q_blk, do_blk, lse_blk, delta_blk, qs_blk = inputs
-                s = jnp.einsum("bhmd,bkhd->bhmk", q_blk, k_blk)
-                if softcap is not None:
-                    t = jnp.tanh(s / softcap)
-                    s = softcap * t
-                mask = spec.mask(qi, ki, qs_blk, ks_blk)[:, None, None]
-                p = jnp.where(
-                    mask, jnp.exp(s - lse_blk[..., None]).reshape(
-                        B, Hkv, g, bq, bk), 0.0).reshape(s.shape)
-                dp = jnp.einsum("bhmd,bkhd->bhmk", do_blk, v_blk)
-                ds = p * (dp - delta_blk[..., None])
-                if softcap is not None:
-                    ds = ds * (1.0 - t * t)
-                dq_blk = jnp.einsum("bhmk,bkhd->bhmd", ds, k_blk) * scale
-                dk = dk + jnp.einsum("bhmk,bhmd->bkhd", ds, q_blk)
-                dv = dv + jnp.einsum("bhmk,bhmd->bkhd", p, do_blk)
-                return (dk, dv), dq_blk
-
-            zeros = jnp.zeros((B, bk, Hkv, D), jnp.float32)
-            (dk, dv), dq_blks = jax.lax.scan(
-                q_step, (zeros, zeros),
-                (jnp.arange(n_q), qf, dof, lse, delta, qsb))
-            return dq + dq_blks, (dk, dv)
-
-        dq, (dk, dv) = jax.lax.scan(
-            kv_block, jnp.zeros_like(qf), (jnp.arange(n_k), kf, vf, ksb))
+        kv_major = sorted(spec.live_pairs(n_q, n_k), key=lambda p: p[::-1])
+        (dq, dk, dv), _ = jax.lax.scan(
+            pair_step, (jnp.zeros_like(qf), jnp.zeros_like(kf),
+                        jnp.zeros_like(vf)), _pair_indices(kv_major))
         dq = jnp.moveaxis(dq.reshape(n_q, B, Hkv, g, bq, D), (0, 4), (1, 2))
         dq = dq.reshape(B, Sq, Hq, D)
         dk = jnp.moveaxis(dk, 0, 1).reshape(B, Sk, Hkv, D)

@@ -14,6 +14,8 @@ the enclosing span on the same thread, the thread) and also enters a
 session it lies on the host plane, on the same clock as the device's
 operations.  ``spans()`` returns what was kept, ``reset()`` clears it and
 ``export(path)`` writes it as Chrome trace events, which Perfetto opens.
+``count(name, n)`` adds to a named total, also only when tracing is on;
+``counters()`` returns the totals and ``reset()`` clears them too.
 
 Span names are dotted, ``<layer>.<what>``:
 
@@ -24,6 +26,12 @@ Span names are dotted, ``<layer>.<what>``:
 - ``feed.put``: one batch's ``device_put``;
 - ``train.dispatch``, ``train.loss_sync``, ``train.save``: the train
   loop's step dispatch, its wait for the loss, a checkpoint.
+
+Counter names are dotted the same way:
+
+- ``attn.pairs_live``, ``attn.pairs_all``: per trace of a chunked XLA
+  attention call, the block pairs its scans run and those of its whole
+  grid (``kernels/flash_attention/ops.py``).
 """
 
 from __future__ import annotations
@@ -33,9 +41,10 @@ import json
 import os
 import threading
 import time
-from typing import List, NamedTuple, Optional
+from typing import Dict, List, NamedTuple, Optional
 
-__all__ = ["Span", "span", "enable", "disable", "spans", "reset", "export"]
+__all__ = ["Span", "span", "enable", "disable", "spans", "reset", "export",
+           "count", "counters"]
 
 
 class Span(NamedTuple):
@@ -64,6 +73,7 @@ _OFF = _Off()
 _on = False
 _annotation = None             # jax.profiler.TraceAnnotation, once enabled
 _kept: List[Span] = []
+_counts: Dict[str, int] = {}
 _lock = threading.Lock()
 _ids = itertools.count()
 _local = threading.local()
@@ -105,6 +115,14 @@ def span(name: str):
     return _On(name)
 
 
+def count(name: str, n: int = 1) -> None:
+    """Add ``n`` to the total ``name`` when tracing is on."""
+    if not _on:
+        return
+    with _lock:
+        _counts[name] = _counts.get(name, 0) + n
+
+
 def enable() -> None:
     global _on, _annotation
     from jax.profiler import TraceAnnotation
@@ -124,9 +142,16 @@ def spans() -> List[Span]:
         return list(_kept)
 
 
+def counters() -> Dict[str, int]:
+    """Every counter's total since the last ``reset()``."""
+    with _lock:
+        return dict(_counts)
+
+
 def reset() -> None:
     with _lock:
         _kept.clear()
+        _counts.clear()
 
 
 def export(path: str) -> str:

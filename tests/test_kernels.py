@@ -8,6 +8,7 @@ import pytest
 from _hypothesis_shim import given, settings, st
 
 from repro.kernels.flash_attention import attention_reference, flash_attention
+from repro.kernels.flash_attention.ops import _Blocks
 from repro.kernels.rglru import rglru, rglru_reference, rglru_step
 from repro.kernels.ssd import ssd, ssd_reference, ssd_step
 
@@ -117,6 +118,17 @@ _GRAD_CASES = {
                  jnp.float32),
     "bf16": (2, 128, 128, 4, 2, 64, True, None, None, True, 0, 64, 64,
              jnp.bfloat16),
+    # A 4 x 2 grid, as in the train step's default blocks at S = 2048.
+    "rect_blocks": (2, 256, 256, 4, 2, 32, True, None, None, True, 0, 64,
+                    128, jnp.float32),
+    # The window leaves out pairs below the diagonal as well as above it.
+    "window_rect": (2, 256, 256, 4, 2, 32, True, 48, None, False, 0, 32, 64,
+                    jnp.float32),
+    "q_offset_rect": (1, 128, 256, 4, 2, 32, True, None, None, False, 128,
+                      32, 64, jnp.float32),
+    # The last q block's window lies wholly past the last key.
+    "dead_q_block": (2, 256, 128, 4, 2, 32, True, 32, None, False, 0, 64, 64,
+                     jnp.float32),
 }
 
 
@@ -164,6 +176,64 @@ def test_flash_xla_vjp_keeps_no_score_block():
         impl="xla", block_q=bq, block_k=bk), q, k, v)
     sizes = [x.size for x in jax.tree.leaves(vjp)]
     assert sizes and max(sizes) < B * Hq * bq * bk, sizes
+
+
+def _exhaustive_live_pairs(spec, n_q, n_k):
+    return [(qi, ki) for qi in range(n_q) for ki in range(n_k)
+            if bool(spec.mask(qi, ki, None, None).any())]
+
+
+@pytest.mark.parametrize("bq, bk", [(32, 64), (64, 32), (64, 128)])
+@pytest.mark.parametrize("q_offset", [0, 97])
+@pytest.mark.parametrize("window", [None, 24, 35, 200])
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_xla_live_pairs_match_the_static_mask(causal, window, q_offset,
+                                                    bq, bk):
+    """The pairs the chunked path runs are exactly those in which the static
+    mask (causal, window, q offset; no segments) holds somewhere, q-major.
+    At q offset 97, some pairs see one key alone, at a corner: with blocks
+    of 32 and 64, q block 0 ends on kv block 2's first key, and the window
+    of 35 reaches q block 0's first query to kv block 0's last key."""
+    Sq, Sk = 128, 256
+    spec = _Blocks(causal, window, None, q_offset, 1.0, bq, bk, False)
+    n_q, n_k = Sq // bq, Sk // bk
+    pairs = spec.live_pairs(n_q, n_k)
+    assert pairs == _exhaustive_live_pairs(spec, n_q, n_k)
+    if not causal and window is None:
+        assert len(pairs) == n_q * n_k
+
+
+def test_flash_xla_live_pairs_at_the_train_steps_grid():
+    """S = 2048 in q blocks of 512 and kv blocks of 1024: the causal mask
+    leaves 6 of the 8 pairs."""
+    spec = _Blocks(True, None, None, 0, 1.0, 512, 1024, False)
+    pairs = spec.live_pairs(4, 2)
+    assert pairs == _exhaustive_live_pairs(spec, 4, 2)
+    assert pairs == [(0, 0), (1, 0), (2, 0), (2, 1), (3, 0), (3, 1)]
+
+
+def test_flash_xla_q_block_with_no_live_pair():
+    """A q block that no pair reaches keeps output 0, as do the rows of a
+    live block that see no key, and no gradient is NaN."""
+    B, Sq, Sk, Hq, Hkv, D, window, bq, bk = 2, 256, 128, 4, 2, 32, 32, 64, 64
+    spec = _Blocks(True, window, None, 0, 1.0, bq, bk, False)
+    assert not any(qi == 3 for qi, _ in spec.live_pairs(Sq // bq, Sk // bk))
+    q, k, v = _qkv(jax.random.PRNGKey(10), B, Sq, Sk, Hq, Hkv, D,
+                   jnp.float32)
+
+    def attn(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               impl="xla", block_q=bq, block_k=bk)
+
+    out = np.asarray(attn(q, k, v))
+    _assert_close(out, attention_reference(q, k, v, causal=True,
+                                           window=window), jnp.float32)
+    blind = np.arange(Sq) - window >= Sk - 1       # rows that see no key
+    assert blind[3 * bq:].all() and blind.sum() > bq
+    assert np.all(out[:, blind] == 0) and np.abs(out[:, ~blind]).max() > 0
+    grads = jax.grad(lambda q, k, v: jnp.sum(attn(q, k, v) ** 2),
+                     (0, 1, 2))(q, k, v)
+    assert all(np.isfinite(np.asarray(g)).all() for g in grads)
 
 
 @settings(max_examples=12, deadline=None)

@@ -14,6 +14,7 @@ from jax.experimental.compilation_cache import compilation_cache
 from repro import obs
 from repro.configs import get_smoke_config
 from repro.configs.base import ModelConfig
+from repro.kernels.flash_attention import flash_attention
 from repro.models import RuntimeConfig, build_model
 from repro.train import TrainConfig, make_train_step
 from repro.train.optimizer import make_optimizer
@@ -38,6 +39,17 @@ def test_off_span_is_one_shared_noop():
         with obs.span("b"):
             pass
     assert obs.spans() == []
+
+
+def test_off_count_is_a_noop_and_reset_clears_counts():
+    obs.count("attn.pairs_live", 6)
+    assert obs.counters() == {}
+    obs.enable()
+    obs.count("a", 2)
+    obs.count("a")
+    assert obs.counters() == {"a": 3}
+    obs.reset()
+    assert obs.counters() == {}
 
 
 def test_nested_spans_keep_their_parent():
@@ -287,6 +299,17 @@ def test_score_block_products_carry_attn_core():
                 and "rematted_computation" not in m.group(4)):
             backward += 1
     assert backward >= 2, "no score block of the backward found"
+
+
+def test_attention_trace_counts_its_live_block_pairs():
+    """One trace of the chunked attention at ``_step_hlo``'s shape (64
+    positions, q blocks of 16, kv blocks of 32): the causal mask leaves 6
+    pairs of the 4 x 2 grid."""
+    obs.enable()
+    x = jax.ShapeDtypeStruct((2, 64, 4, 16), jnp.float32)
+    jax.eval_shape(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, impl="xla", block_q=16, block_k=32), x, x, x)
+    assert obs.counters() == {"attn.pairs_live": 6, "attn.pairs_all": 8}
 
 
 def test_ssd_products_carry_ssm_ssd():
