@@ -1,9 +1,9 @@
 """Kernel micro-benchmarks (CPU host): wall time of the jitted XLA paths +
 interpret-mode correctness deltas vs the oracles.
 
-Real kernel perf is a TPU measurement; on this CPU container the meaningful
-numbers are (a) the XLA-path throughput used by the dry-run lowerings and
-(b) max|err| vs the pure-jnp oracle, proving the Pallas kernels' math.
+Real kernel perf is a TPU measurement; on a CPU host the meaningful
+numbers are (a) the XLA-path throughput and (b) max|err| vs the pure-jnp
+oracle, proving the Pallas kernels' math.
 """
 
 from __future__ import annotations

@@ -2,9 +2,8 @@
 a few hundred steps on CPU, with mid-run checkpoint/restart through the
 dataset manager.
 
-This is deliverable (b)'s "end-to-end driver": the ~100M config is the
-stablelm family reduced to ~100M params (same code path as the full
-assigned config; the full sizes are exercised by the dry-run).
+The ~100M config is the stablelm family reduced to ~100M params (the same
+code path as the full config).
 
 Run:  PYTHONPATH=src python examples/train_end_to_end.py [--steps 200]
 """
@@ -14,8 +13,6 @@ import dataclasses
 import sys
 
 sys.path.insert(0, "src")
-
-import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.launch import train as train_mod

@@ -1,5 +1,5 @@
 """repro — "Dataset Management Platform for Machine Learning" (TDCommons
-5690, Feb 2023) reproduced as a production-grade multi-pod JAX framework.
+5690, Feb 2023) reproduced as a JAX framework that trains on TPU.
 
 Subpackages:
   core     the paper's platform (storage engine, versioning, dataset
@@ -9,7 +9,7 @@ Subpackages:
   kernels  Pallas TPU kernels (flash attention, SSD, RG-LRU) + oracles
   train    optimizers, sharding rules, train step, platform checkpointing
   serve    batched serving engine
-  launch   production meshes, multi-pod dry-run, drivers, layout presets
+  launch   device mesh, train and serve drivers
   configs  architecture registry (--arch ids)
 """
 

@@ -1,18 +1,17 @@
 """Architecture registry: ``--arch <id>`` resolves here.
 
-10 assigned architectures x 4 shapes = 40 cells.  ``cells()`` enumerates
-them with runnability (long_500k needs sub-quadratic attention; the skip
-rule is documented in DESIGN.md §Arch-applicability).
+The ten architectures at their published sizes, and a smoke-sized
+config of each for CPU tests.
 """
 
 from __future__ import annotations
 
-from typing import Dict, List
+from typing import List
 
 from . import (arctic_480b, gemma2_9b, gemma3_12b, internvl2_2b, mamba2_1_3b,
                mixtral_8x22b, qwen2_5_32b, recurrentgemma_9b,
                seamless_m4t_medium, stablelm_1_6b)
-from .base import SHAPES, Cell, ModelConfig, ShapeConfig
+from .base import ModelConfig
 
 _MODULES = {
     "qwen2.5-32b": qwen2_5_32b,
@@ -41,18 +40,4 @@ def get_smoke_config(arch: str) -> ModelConfig:
     return _MODULES[arch].smoke_config()
 
 
-def cell_runnable(arch: str, shape: str) -> Cell:
-    cfg = get_config(arch)
-    if shape == "long_500k" and not cfg.subquadratic:
-        return Cell(arch, shape, False,
-                    "full-attention arch: 500k decode is quadratic "
-                    "(global/full layers); skip per assignment rule")
-    return Cell(arch, shape, True)
-
-
-def cells() -> List[Cell]:
-    return [cell_runnable(a, s) for a in ARCHS for s in SHAPES]
-
-
-__all__ = ["ARCHS", "SHAPES", "Cell", "ModelConfig", "ShapeConfig",
-           "get_config", "get_smoke_config", "cells", "cell_runnable"]
+__all__ = ["ARCHS", "ModelConfig", "get_config", "get_smoke_config"]

@@ -1,17 +1,11 @@
-"""Config system: architecture + shape definitions for the assigned pool.
-
-Every architecture in the assignment is a :class:`ModelConfig`; every
-input-shape a :class:`ShapeConfig`.  A *cell* is (arch × shape); the dry-run
-and roofline sweep iterate cells.
-"""
+"""Model configuration: one frozen :class:`ModelConfig` per architecture."""
 
 from __future__ import annotations
 
-import dataclasses
-from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from dataclasses import dataclass
+from typing import Dict, Optional, Tuple
 
-__all__ = ["ModelConfig", "ShapeConfig", "SHAPES", "Cell", "round_up"]
+__all__ = ["ModelConfig", "round_up"]
 
 
 def round_up(x: int, m: int) -> int:
@@ -66,7 +60,7 @@ class ModelConfig:
     n_encoder_layers: int = 0
     is_encoder_decoder: bool = False
 
-    # --- modality frontend (STUB: precomputed embeddings via input_specs) ------
+    # --- modality frontend (STUB: precomputed embeddings, frontend_embeds) ----
     frontend: Optional[str] = None          # "audio" | "vision"
     frontend_tokens: int = 0                # patches/frames occupying the prefix
 
@@ -88,18 +82,6 @@ class ModelConfig:
     @property
     def attention_free(self) -> bool:
         return self.family == "ssm"
-
-    @property
-    def subquadratic(self) -> bool:
-        """True iff *no* layer attends to unbounded context (long_500k ok)."""
-        if self.family == "ssm":
-            return True
-        kinds = set(self.pattern)
-        if "global" in kinds or "attn" in kinds:
-            # plain/global attention is unbounded unless SWA caps it
-            return self.sliding_window is not None
-        # only local/rec/ssm kinds left -> bounded windows
-        return True
 
     @property
     def d_inner(self) -> int:
@@ -165,26 +147,3 @@ class ModelConfig:
         active = self.n_layers * self.experts_per_token * 3 * self.d_model * self.moe_d_ff
         return int(full - all_experts + active)
 
-
-@dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str                 # "train" | "prefill" | "decode"
-
-
-SHAPES: Dict[str, ShapeConfig] = {
-    "train_4k": ShapeConfig("train_4k", 4_096, 256, "train"),
-    "prefill_32k": ShapeConfig("prefill_32k", 32_768, 32, "prefill"),
-    "decode_32k": ShapeConfig("decode_32k", 32_768, 128, "decode"),
-    "long_500k": ShapeConfig("long_500k", 524_288, 1, "decode"),
-}
-
-
-@dataclass(frozen=True)
-class Cell:
-    arch: str
-    shape: str
-    runnable: bool
-    skip_reason: str = ""

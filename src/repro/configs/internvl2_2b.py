@@ -1,8 +1,9 @@
 """internvl2-2b — InternViT (STUB) + InternLM2 language backbone.
 
 [arXiv:2404.16821; hf] 24L d_model=2048 16H (GQA kv=8) d_ff=8192 vocab=92553
-(padded to 92672).  The vision frontend is a stub: ``input_specs()`` provides
-precomputed patch embeddings occupying the sequence prefix.
+(padded to 92672).  The vision frontend is a stub: the batch's
+``frontend_embeds`` are precomputed patch embeddings occupying the sequence
+prefix.
 """
 
 import dataclasses

@@ -1,8 +1,8 @@
 """seamless-m4t-medium — encoder-decoder multimodal (audio frontend STUB).
 
 [arXiv:2308.11596; hf] 12L d_model=1024 16H (GQA kv=16) d_ff=4096
-vocab=256206.  The speech frontend is a stub: ``input_specs()`` provides
-precomputed frame embeddings (B, S_enc, d_model).
+vocab=256206.  The speech frontend is a stub: the batch's ``frontend_embeds``
+are precomputed frame embeddings (B, S_enc, d_model).
 """
 
 import dataclasses

@@ -100,9 +100,8 @@ def _flash_xla(
         kv_segments = jnp.zeros((B, Sk), jnp.int32)
 
     if n_q == 1 and n_k == 1:
-        # Single block: no loops — the whole computation is explicit HLO
-        # (used by the roofline dry-run so cost_analysis sees the attention
-        # FLOPs; XLA never counts lax.scan/map bodies).
+        # Single block: no loops to run, so the reference computes the
+        # whole attention directly and autodiff gives its backward.
         return attention_reference(
             q, k, v, causal=causal, window=window, softcap=softcap,
             q_segments=q_segments if use_segments else None,

@@ -60,9 +60,9 @@ def ssd_step(state, x_t, log_a_t, b_t, c_t):
 def _ssd_xla(x, log_a, B_mat, C_mat, initial_state, *, chunk):
     """Blocked SSD in pure jnp: scan over chunks, matmuls within.
 
-    Same math as the Pallas kernel; used for CPU dry-run lowering so the
-    compiled HLO reflects the blocked algorithm (chunk-quadratic intra +
-    state passing), not a length-S sequential scan.
+    Same math as the Pallas kernel (chunk-quadratic intra-chunk terms +
+    state passing between chunks), not a length-S sequential scan.  It is
+    the path the train step differentiates.
     """
     Bsz, S, H, P = x.shape
     N = B_mat.shape[-1]

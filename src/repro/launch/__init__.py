@@ -1,6 +1,4 @@
-# Launch layer: production mesh, multi-pod dry-run, train/serve drivers.
-# NOTE: do NOT import dryrun here — it sets XLA_FLAGS at import time and
-# must stay an explicit entry point.
-from .mesh import make_local_mesh, make_production_mesh
+# Launch layer: the device mesh and the train/serve drivers.
+from .mesh import make_local_mesh
 
-__all__ = ["make_local_mesh", "make_production_mesh"]
+__all__ = ["make_local_mesh"]

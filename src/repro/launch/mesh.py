@@ -1,11 +1,7 @@
-"""Production mesh construction.
-
-Single pod: 256 TPU v5e chips as (16, 16) over ("data", "model").
-Multi-pod: 2 pods = 512 chips as (2, 16, 16) over ("pod", "data", "model")
-— the "pod" axis maps to DCN; pure data parallelism crosses it.
+"""Device meshes for the train and serve drivers.
 
 A FUNCTION (not a module constant) so importing this module never touches
-jax device state; the dry-run sets XLA_FLAGS before first jax init.
+jax device state.
 """
 
 from __future__ import annotations
@@ -16,13 +12,7 @@ import jax
 import numpy as np
 from jax.sharding import AxisType, Mesh
 
-__all__ = ["make_production_mesh", "make_local_mesh"]
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+__all__ = ["make_local_mesh"]
 
 
 def make_local_mesh(devices: Union[int, Sequence, None] = None) -> Mesh:

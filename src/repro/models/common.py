@@ -31,7 +31,6 @@ class RuntimeConfig:
     ssd_impl: str = "auto"
     rglru_impl: str = "auto"
     remat: str = "none"                  # none | full | dots
-    scan_layers: bool = True
     attn_block_q: int = 512
     attn_block_k: int = 1024
     moe_group_size: int = 512
@@ -39,9 +38,6 @@ class RuntimeConfig:
     # ActivationSharding (train/sharding.py) or None; models call
     # .hidden()/.logits() at the constraint points when set.
     act_sharding: Any = None
-    # Pin q/k/v head sharding explicitly (hillclimb lever for archs whose
-    # head count does not divide the tp axis).
-    constrain_attn_heads: bool = False
     # MoE execution path: "gspmd" (capacity einsums under pjit) or
     # "shard_map" (explicit all_to_all expert parallelism).
     moe_impl: str = "gspmd"
@@ -54,15 +50,6 @@ class RuntimeConfig:
 
     def logits_constraint(self, x):
         return self.act_sharding.logits(x) if self.act_sharding else x
-
-    def heads_constraint(self, x):
-        if self.act_sharding and self.constrain_attn_heads:
-            return self.act_sharding.heads(x)
-        return x
-
-    def moe_constraint(self, x):
-        return (self.act_sharding.moe_expert_major(x)
-                if self.act_sharding else x)
 
 
 class Initializer:

@@ -174,12 +174,7 @@ class DecoderLM:
 
         if self.n_repeats:
             body = _remat(superblock, self.rt.remat)
-            if self.rt.scan_layers:
-                x, _ = jax.lax.scan(body, x, params["blocks"])
-            else:
-                for r in range(self.n_repeats):
-                    layer = jax.tree.map(lambda a, r=r: a[r], params["blocks"])
-                    x, _ = body(x, layer)
+            x, _ = jax.lax.scan(body, x, params["blocks"])
         for j in range(self.n_tail):
             x = self._apply_block(self.pattern[j], params["tail"][f"tail{j}"],
                                   x, positions=positions, segments=segments)
@@ -312,9 +307,8 @@ class DecoderLM:
                         layer_cache[f"pos{j}"], pos, context_start)
                 return y, updates
 
-            x, new_cache["blocks"] = _scan_or_unroll(
-                body, x, (params["blocks"], cache["blocks"]),
-                self.n_repeats, self.rt.scan_layers)
+            x, new_cache["blocks"] = jax.lax.scan(
+                body, x, (params["blocks"], cache["blocks"]))
         for j in range(self.n_tail):
             x, new_cache[f"tail{j}"] = self._decode_block(
                 self.pattern[j], params["tail"][f"tail{j}"], x,
@@ -351,9 +345,8 @@ class DecoderLM:
                         layer_cache[f"pos{j}"], positions, segments)
                 return y, updates
 
-            x, filled["blocks"] = _scan_or_unroll(
-                body, x, (params["blocks"], cache["blocks"]),
-                self.n_repeats, self.rt.scan_layers)
+            x, filled["blocks"] = jax.lax.scan(
+                body, x, (params["blocks"], cache["blocks"]))
         for j in range(self.n_tail):
             x, filled[f"tail{j}"] = self._prefill_block(
                 self.pattern[j], params["tail"][f"tail{j}"], x,
@@ -394,23 +387,6 @@ class DecoderLM:
         if cfg.post_norms:
             y = norm_apply(p["post_norm2"], y, cfg.norm)
         return x + y, new_cache
-
-
-def _scan_or_unroll(body, carry, xs, n: int, use_scan: bool):
-    """lax.scan, or a Python unroll producing identical (carry, stacked ys).
-
-    The unroll exists for the roofline dry-run: XLA's cost_analysis reports
-    zero for scan bodies, so accurate per-step FLOPs need explicit layers.
-    """
-    if use_scan:
-        return jax.lax.scan(body, carry, xs)
-    ys = []
-    for r in range(n):
-        x_r = jax.tree.map(lambda a, r=r: a[r], xs)
-        carry, y = body(carry, x_r)
-        ys.append(y)
-    stacked = jax.tree.map(lambda *zs: jnp.stack(zs), *ys)
-    return carry, stacked
 
 
 def xent_loss(logits: jnp.ndarray, labels: jnp.ndarray):

@@ -81,12 +81,10 @@ def moe_apply(params: Dict, x: jnp.ndarray, cfg: ModelConfig,
 
     cd = x.dtype
     xd = jnp.einsum("gtec,gtd->gecd", dispatch.astype(cd), xg)  # (G,E,C,D)
-    xd = rt.moe_constraint(xd)          # -> expert-major (all-to-all under EP)
     h = jnp.einsum("gecd,edf->gecf", xd, params["wi"].astype(cd))
     gt = jnp.einsum("gecd,edf->gecf", xd, params["wg"].astype(cd))
     h = h * jax.nn.silu(gt)
     ye = jnp.einsum("gecf,efd->gecd", h, params["wo"].astype(cd))
-    ye = rt.moe_constraint(ye)          # stay expert-major until combine
     y = jnp.einsum("gtec,gecd->gtd", combine.astype(cd), ye)
     return y.reshape(B, S, D), aux
 

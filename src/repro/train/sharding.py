@@ -1,16 +1,19 @@
-"""Logical-axis sharding rules -> PartitionSpecs for params / batch / cache.
+"""Sharding rules -> PartitionSpecs for params, optimizer state and batches.
 
-MaxText-style: parameters are matched by their tree path (names are stable
-across the model zoo) and given PartitionSpecs built from a rule table.
-Rules adapt to the mesh (axis sizes must divide the dim) and to the shape
-kind (train / prefill / decode / long-decode).
+Parameters are matched by their tree path (names are stable across the
+model zoo) and given PartitionSpecs built from a rule table.  An axis shards
+a dim only where its size divides the dim, and axes the mesh lacks are
+dropped.
 
-Baseline layout (hillclimbed in EXPERIMENTS.md §Perf):
-- batch        -> ("pod", "data")     (replicated when batch==1, long_500k)
-- d_ff / heads -> "model"             (tensor parallel)
-- d_model rows of big matrices -> "data"  (FSDP; gathered on use)
-- vocab        -> "model"
-- MoE experts  -> "data" when divisible (arctic 128/16), else d_ff/"model"
+Roles and their default mesh axes:
+- batch        -> ("pod", "data")
+- d_model rows of big matrices -> fsdp_axis "data"  (FSDP; gathered on use)
+- d_ff / heads / vocab         -> tp_axis "model"   (tensor parallel)
+- MoE experts  -> expert_axis "data" when it divides the expert count
+
+With ``batch_axes=("data",), fsdp_axis=None, tp_axis=None`` on the 1-D mesh
+of ``make_local_mesh`` every param is replicated and the batch is split over
+"data": plain data parallelism.
 """
 
 from __future__ import annotations
@@ -19,17 +22,16 @@ import re
 from typing import Any, Dict, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-__all__ = ["ShardingRules", "param_specs", "batch_specs", "cache_specs",
+__all__ = ["ShardingRules", "param_specs", "batch_specs",
            "opt_state_specs", "named", "constrain"]
 
 PyTree = Any
 
 
 class ShardingRules:
-    """Maps logical roles to mesh axes; override per experiment."""
+    """Maps logical roles to mesh axes."""
 
     def __init__(
         self,
@@ -39,10 +41,6 @@ class ShardingRules:
         fsdp_axis: Optional[str] = "data",
         tp_axis: Optional[str] = "model",
         expert_axis: Optional[str] = "data",
-        shard_activations_embed: bool = False,
-        attn_shard_mode: str = "heads",      # heads | seq
-        moe_layout: str = "none",            # none | expert_major | grid
-        seq_axis=None,                       # activation seq-dim sharding
     ):
         self.mesh = mesh
         names = mesh.axis_names
@@ -57,10 +55,6 @@ class ShardingRules:
         self.fsdp_axis = _valid(fsdp_axis)
         self.tp_axis = _valid(tp_axis)
         self.expert_axis = _valid(expert_axis)
-        self.shard_activations_embed = shard_activations_embed
-        self.attn_shard_mode = attn_shard_mode
-        self.moe_layout = moe_layout
-        self.seq_axis = _valid(seq_axis)
 
     def size(self, axis) -> int:
         if axis is None:
@@ -245,7 +239,7 @@ def opt_state_specs(opt_state: PyTree, params: PyTree, pspecs: PyTree,
 
 
 # ---------------------------------------------------------------------------
-# Batch / cache specs
+# Batch specs
 # ---------------------------------------------------------------------------
 
 
@@ -263,31 +257,6 @@ def batch_specs(batch: PyTree, rules: ShardingRules) -> PyTree:
     return jax.tree_util.tree_map_with_path(assign, batch)
 
 
-def cache_specs(cache: PyTree, rules: ShardingRules, batch: int) -> PyTree:
-    """Decode caches: batch over batch_axes; heads/width dims over tp."""
-    b_axes = rules.batch_spec_axes(batch)
-
-    def assign(path, leaf):
-        shp = tuple(leaf.shape)
-        name = _path_str(path)
-        stacked = name.startswith("blocks/") or name.startswith("self/") \
-            or name.startswith("cross/")
-        lead = (None,) if stacked else ()
-        body = shp[1:] if stacked else shp
-        # KV cache (B, L, Hkv, dh): shard heads*... on tp if divisible
-        axes = [None] * len(body)
-        if len(body) >= 1:
-            axes[0] = b_axes if body[0] == batch else None
-        for i in range(len(body) - 1, 0, -1):
-            a = rules.axis_if_divides(rules.tp_axis, body[i])
-            if a:
-                axes[i] = a
-                break
-        return P(*(lead + tuple(axes)))
-
-    return jax.tree_util.tree_map_with_path(assign, cache)
-
-
 def named(mesh: Mesh, spec_tree: PyTree) -> PyTree:
     return jax.tree.map(
         lambda s: NamedSharding(mesh, s), spec_tree,
@@ -301,9 +270,8 @@ def constrain(x, rules: ShardingRules, spec: P):
 class ActivationSharding:
     """Constraint points the models call (via RuntimeConfig.act_sharding).
 
-    Keeps GSPMD propagation on the rails: batch over the data axes, vocab
-    (logits) over tp, and optionally the embed dim over tp ("2D activation
-    sharding", a hillclimb lever).  No-op when unset (CPU tests).
+    Keeps GSPMD propagation on the rails: batch over the data axes and
+    vocab (logits) over tp.  No-op when unset (CPU tests).
     """
 
     def __init__(self, rules: ShardingRules):
@@ -315,69 +283,11 @@ class ActivationSharding:
         return P(b_axes, *mid, last_axis)
 
     def hidden(self, x):
-        """(B, S, D) residual-stream activations.
-
-        With ``seq_axis`` set (ZeRO-3 + sequence parallelism, used when
-        global_batch < chips, e.g. the multi-pod mesh), the seq dim is
-        sharded too: per-token ops run 1/seq_axis per device and attention
-        consumes it via the "seq" shard mode.
-        """
-        r = self.rules
-        tp = (r.axis_if_divides(r.tp_axis, x.shape[-1])
-              if r.shard_activations_embed else None)
-        if (r.seq_axis is not None and x.ndim == 3 and x.shape[1] > 1
-                and x.shape[1] % r.size(r.seq_axis) == 0):
-            b_axes = r.batch_spec_axes(x.shape[0])
-            return constrain(x, r, P(b_axes, r.seq_axis, tp))
-        return constrain(x, r, self._spec(x, tp))
+        """(B, S, D) residual-stream activations: batch over the data axes."""
+        return constrain(x, self.rules, self._spec(x, None))
 
     def logits(self, x):
         """(B, S, V_pad) — vocab over tp (Megatron layout: no gather)."""
         r = self.rules
         return constrain(
             x, r, self._spec(x, r.axis_if_divides(r.tp_axis, x.shape[-1])))
-
-    def moe_expert_major(self, x):
-        """(G, E, C, D/F) dispatched MoE activations: EXPERT-major layout
-        (E over the expert axis).  The reshard from token-major (G over
-        data) to expert-major lowers to an all-to-all — classic expert
-        parallelism — instead of the replicate+all-reduce GSPMD otherwise
-        invents for the expert einsums (measured 17 GiB all-reduces on
-        arctic-480b).
-
-        MEASURED RESULT (EXPERIMENTS.md §Perf, arctic iteration): GSPMD
-        lowers this reshard to replicate+slice, NOT all-to-all — collective
-        time got 3x WORSE, so it is OFF by default
-        (rules.moe_expert_major).  The proper fix is a shard_map MoE with
-        explicit lax.all_to_all (documented future work)."""
-        r = self.rules
-        if r.moe_layout == "grid":
-            # GRID layout: token-groups over tp, experts over the expert
-            # axis.  BOTH expert-einsum operands are sharded on FREE dims
-            # (g on tp, e on data), so the big (G,E,C,*) einsums need NO
-            # communication at all; only the cheap token-major <-> grid
-            # reshards at the MoE boundary move data.
-            ga = r.axis_if_divides(r.tp_axis, x.shape[0])
-            ea = r.axis_if_divides(r.expert_axis, x.shape[1])
-            return constrain(x, r, P(ga, ea, None, None))
-        if r.moe_layout != "expert_major":
-            return x
-        ea = r.axis_if_divides(r.expert_axis, x.shape[1])
-        return constrain(x, r, P(None, ea, None, None))
-
-    def heads(self, x):
-        """(B, S, H, dh) q/k/v.
-
-        mode "heads": heads over tp when divisible, else explicitly
-        REPLICATED (stops GSPMD from inventing pathological head_dim/padded
-        shardings when H % tp != 0, e.g. qwen's 40 heads on 16).
-        mode "seq": context parallelism — the SEQUENCE dim over tp; GSPMD
-        all-gathers the (small, GQA) K/V while the S^2 score work stays
-        1/tp per device regardless of head count."""
-        r = self.rules
-        b_axes = r.batch_spec_axes(x.shape[0])
-        if r.attn_shard_mode == "seq" and x.shape[1] % max(
-                r.size(r.tp_axis), 1) == 0 and x.shape[1] > 1:
-            return constrain(x, r, P(b_axes, r.tp_axis, None, None))
-        h_axis = r.axis_if_divides(r.tp_axis, x.shape[2])
-        return constrain(x, r, P(b_axes, None, h_axis, None))

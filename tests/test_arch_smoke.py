@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import ARCHS, SHAPES, cells, get_config, get_smoke_config
+from repro.configs import ARCHS, get_config, get_smoke_config
 from repro.models import RuntimeConfig, build_model
 
 RT = RuntimeConfig(compute_dtype=jnp.float32, attn_impl="naive",
@@ -173,17 +173,6 @@ def test_configs_match_assignment():
     iv = get_config("internvl2-2b")
     assert (iv.n_layers, iv.d_model, iv.n_heads, iv.n_kv_heads, iv.d_ff,
             iv.vocab_size) == (24, 2048, 16, 8, 8192, 92553)
-
-
-def test_cells_enumeration():
-    cs = cells()
-    assert len(cs) == 40
-    runnable = [c for c in cs if c.runnable]
-    skipped = [c for c in cs if not c.runnable]
-    assert len(skipped) == 7          # 7 full-attention long_500k skips
-    assert all(c.shape == "long_500k" for c in skipped)
-    long_ok = {c.arch for c in runnable if c.shape == "long_500k"}
-    assert long_ok == {"mixtral-8x22b", "recurrentgemma-9b", "mamba2-1.3b"}
 
 
 def test_param_counts_in_expected_band():

@@ -1,12 +1,11 @@
-"""Sharding-rule and HLO-analysis unit tests (no big meshes needed)."""
+"""Sharding-rule unit tests (no big meshes needed)."""
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.configs import get_config, get_smoke_config
-from repro.launch.hlo_analysis import (HW, parse_collectives, roofline_terms)
+from repro.configs import get_config
 from repro.models import RuntimeConfig, build_model
 from repro.train.sharding import ShardingRules, batch_specs, param_specs
 
@@ -94,7 +93,7 @@ def test_batch_specs_shard_batch(rules, rules_mp):
     batch = {"tokens": jax.ShapeDtypeStruct((256, 4096), jnp.int32)}
     assert batch_specs(batch, rules)["tokens"] == P(("data",), None)
     assert batch_specs(batch, rules_mp)["tokens"] == P(("pod", "data"), None)
-    # batch=1 (long_500k): replicated
+    # batch=1: replicated
     one = {"tokens": jax.ShapeDtypeStruct((1, 1), jnp.int32)}
     assert batch_specs(one, rules)["tokens"] == P(None, None)
     # batch=32 on multi-pod: 32 == pod*data -> both axes
@@ -109,51 +108,16 @@ def test_vocab_padding_divisible():
         assert cfg.padded_vocab >= cfg.vocab_size
 
 
-# ---------------------------------------------------------------------------
-# HLO collective parsing
-# ---------------------------------------------------------------------------
-
-HLO_SAMPLE = """
-  %all-reduce.1 = f32[32,512]{1,0} all-reduce(%dot), channel_id=1, replica_groups=[2,4]<=[8], use_global_device_ids=true, to_apply=%add
-  %all-gather.2 = bf16[64,1024]{1,0} all-gather(%p0), channel_id=2, replica_groups={{0,1,2,3},{4,5,6,7}}, dimensions={0}
-  %reduce-scatter.3 = f32[16,128]{1,0} reduce-scatter(%x), channel_id=3, replica_groups=[1,8]<=[8], dimensions={0}, to_apply=%add
-  %all-to-all.4 = bf16[8,256]{1,0} all-to-all(%y), channel_id=4, replica_groups=[2,4]<=[8], dimensions={0}
-  %collective-permute.5 = f32[4,4]{1,0} collective-permute(%z), channel_id=5, source_target_pairs={{0,1}}
-  %cp.done = f32[4,4]{1,0} collective-permute-done(%cp.start)
-"""
-
-
-def test_parse_collectives_counts_and_bytes():
-    stats = parse_collectives(HLO_SAMPLE)
-    assert stats.counts == {"all-reduce": 1, "all-gather": 1,
-                            "reduce-scatter": 1, "all-to-all": 1,
-                            "collective-permute": 1}
-    # all-reduce: 32*512*4 = 65536 B, n=4 -> 2 * 65536 * 3/4 = 98304
-    assert stats.bytes_by_kind["all-reduce"] == pytest.approx(98304)
-    # all-gather: 64*1024*2 = 131072, n=4 -> 131072 * 3/4
-    assert stats.bytes_by_kind["all-gather"] == pytest.approx(98304)
-    # reduce-scatter: result 16*128*4 = 8192, n=8 -> 8192 * 7
-    assert stats.bytes_by_kind["reduce-scatter"] == pytest.approx(57344)
-    # collective-permute: result bytes
-    assert stats.bytes_by_kind["collective-permute"] == pytest.approx(64)
-
-
-def test_roofline_terms_dominant():
-    t = roofline_terms(197e12, 819e9 * 2, 0.0, HW())
-    assert t["compute_s"] == pytest.approx(1.0)
-    assert t["memory_s"] == pytest.approx(2.0)
-    assert t["dominant"] == "memory"
-    assert t["bound_s"] == pytest.approx(2.0)
-
-
-def test_scan_or_unroll_equivalence():
-    from repro.models.decoder import _scan_or_unroll
-
-    def body(c, x):
-        return c + x["a"], {"out": c * 2}
-
-    xs = {"a": jnp.arange(5.0)}
-    c1, y1 = _scan_or_unroll(body, jnp.float32(0), xs, 5, True)
-    c2, y2 = _scan_or_unroll(body, jnp.float32(0), xs, 5, False)
-    assert jnp.allclose(c1, c2)
-    assert jnp.allclose(y1["out"], y2["out"])
+@pytest.mark.parametrize("arch", ["stablelm-1.6b", "mamba2-1.3b"])
+def test_data_parallel_rules_replicate_params_and_shard_batch(arch):
+    """The layout of the benchmark cells and of ``launch/train.py:train``:
+    a 1-D "data" mesh, no FSDP or tensor axis."""
+    r = ShardingRules(FakeMesh({"data": 4}), batch_axes=("data",),
+                      fsdp_axis=None, tp_axis=None)
+    _, params = _abstract_params(arch)
+    specs = param_specs(params, r)
+    for spec in jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, P)):
+        assert all(axis is None for axis in spec), spec
+    batch = {k: jax.ShapeDtypeStruct((8, 16), jnp.int32)
+             for k in ("tokens", "labels", "segments", "positions")}
+    assert batch_specs(batch, r) == {k: P("data", None) for k in batch}
