@@ -5,7 +5,9 @@
 - ``impl="xla"``: memory-efficient chunked flash in pure jnp (one scan over
   the block pairs the causal and window rules leave live, online softmax)
   with its own backward (recomputed score blocks) — the differentiated path
-  of the train step; never materializes (Sq, Sk).
+  of the train step; never materializes (Sq, Sk).  Every product takes its
+  operands at the input dtype and accumulates in float32; the softmax and
+  the accumulators are float32.
 - ``impl="naive"``: the oracle (small shapes / decode single-token).
 - ``impl="auto"``: pallas on TPU, xla for long sequences elsewhere, naive
   when the score matrix is small.
@@ -84,7 +86,8 @@ def _flash_xla(
     pairs of q and kv blocks).  Transient memory is O(bq * bk) per (B, H) —
     never (Sq, Sk), in the backward too (``_chunked_bwd``).  With ``obs``
     on, each trace counts the pairs run (``attn.pairs_live``) of the grid
-    (``attn.pairs_all``)."""
+    (``attn.pairs_all``), and ``attn.operands_narrow`` where the products
+    take operands narrower than float32."""
     B, Sq, Hq, D = q.shape
     _, Sk, Hkv, _ = k.shape
     if scale is None:
@@ -112,6 +115,8 @@ def _flash_xla(
                    use_segments)
     obs.count("attn.pairs_live", len(spec.live_pairs(n_q, n_k)))
     obs.count("attn.pairs_all", n_q * n_k)
+    if jnp.dtype(q.dtype).itemsize < 4:
+        obs.count("attn.operands_narrow", 1)
     return _chunked(spec, q, k, v, q_segments, kv_segments)
 
 
@@ -172,31 +177,36 @@ def _chunked_forward(spec, q, k, v, q_segments, kv_segments):
     its slot of a stack, which starts as a block that has seen no key.
     Returns the output in float32 by q block (n_q, B, Hq, bq, D) and each
     query row's logsumexp (n_q, B, Hq, bq); a row that sees no key has
-    output 0 and logsumexp +inf."""
+    output 0 and logsumexp +inf.
+
+    Both products take their operands at q's dtype and accumulate in
+    float32: the scaled q block, k and v, and the probabilities p, rounded
+    once where they are made.  The mask, the running max and sum and the
+    output stay float32; the sum is taken before p is rounded."""
     B, Sq, Hq, D = q.shape
     _, Sk, Hkv, _ = k.shape
     group = Hq // Hkv
     bq, bk, scale, softcap = spec.bq, spec.bk, spec.scale, spec.softcap
     n_q, n_k = Sq // bq, Sk // bk
+    cdt = q.dtype
+    f32 = jnp.float32
 
     # (n_q, B, bq, Hq, D) / (n_k, B, bk, Hkv, D)
     qb = q.reshape(B, n_q, bq, Hq, D).transpose(1, 0, 2, 3, 4)
-    kb = k.reshape(B, n_k, bk, Hkv, D).transpose(1, 0, 2, 3, 4)
-    vb = v.reshape(B, n_k, bk, Hkv, D).transpose(1, 0, 2, 3, 4)
+    kb = k.astype(cdt).reshape(B, n_k, bk, Hkv, D).transpose(1, 0, 2, 3, 4)
+    vb = v.astype(cdt).reshape(B, n_k, bk, Hkv, D).transpose(1, 0, 2, 3, 4)
     qsb = q_segments.reshape(B, n_q, bq).transpose(1, 0, 2)
     ksb = kv_segments.reshape(B, n_k, bk).transpose(1, 0, 2)
-
-    kf = kb.astype(jnp.float32)
-    vf = vb.astype(jnp.float32)
 
     def pair_step(carry, pair):
         m_all, l_all, acc_all = carry
         qi, ki = pair
-        qf = qb[qi].astype(jnp.float32) * scale          # (B, bq, Hq, D)
-        k_rep = jnp.repeat(kf[ki], group, axis=2)       # (B, bk, Hq, D)
-        v_rep = jnp.repeat(vf[ki], group, axis=2)
+        q_blk = (qb[qi].astype(f32) * scale).astype(cdt)   # (B, bq, Hq, D)
+        k_rep = jnp.repeat(kb[ki], group, axis=2)       # (B, bk, Hq, D)
+        v_rep = jnp.repeat(vb[ki], group, axis=2)
         m_prev, l_prev, acc = m_all[qi], l_all[qi], acc_all[qi]
-        s = jnp.einsum("bqhd,bkhd->bhqk", qf, k_rep)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q_blk, k_rep,
+                       preferred_element_type=f32)
         if softcap is not None:
             s = softcap * jnp.tanh(s / softcap)
         mask = spec.mask(qi, ki, qsb[qi], ksb[ki])[:, None]
@@ -209,7 +219,8 @@ def _chunked_forward(spec, q, k, v, q_segments, kv_segments):
                           jnp.exp(m_prev - m_safe))
         l_new = alpha * l_prev + p.sum(-1)
         acc = acc * alpha[..., None] + jnp.einsum(
-            "bhqk,bkhd->bhqd", p, v_rep)
+            "bhqk,bkhd->bhqd", p.astype(cdt), v_rep,
+            preferred_element_type=f32)
         put = jax.lax.dynamic_update_index_in_dim
         return (put(m_all, m_new, qi, 0), put(l_all, l_new, qi, 0),
                 put(acc_all, acc, qi, 0)), None
@@ -256,13 +267,22 @@ def _chunked_bwd(spec, res, d_out):
     pairs, kv-major with q ascending, adds each pair's dq into its q block's
     slot and its dk, dv into its kv block's.  The q heads that share a kv
     head are stacked on the query axis, so every product is a matmul
-    batched over (B, Hkv) and dk, dv sum the group."""
+    batched over (B, Hkv) and dk, dv sum the group.
+
+    As in the forward, every product takes its operands at q's dtype and
+    accumulates in float32.  The recomputed scores, p, dp and delta are
+    float32.  p is rounded once, where it is made, and both dv and
+    ds = p * (dp - delta) take the rounded copy: so the compiler keeps one
+    copy of p between them, at q's dtype.  ds is formed in float32 and
+    rounded once for dq and dk.  dq, dk and dv add up in float32."""
     q, k, v, q_segments, kv_segments, out, lse = res
     B, Sq, Hq, D = q.shape
     _, Sk, Hkv, _ = k.shape
     g = Hq // Hkv
     bq, bk, scale, softcap = spec.bq, spec.bk, spec.scale, spec.softcap
     n_q, n_k = Sq // bq, Sk // bk
+    cdt = q.dtype
+    f32 = jnp.float32
 
     def q_blocks(x):        # (B, Sq, Hq, ...) -> (n_q, B, Hkv, g * bq, ...)
         x = x.reshape(B, n_q, bq, Hkv, g, *x.shape[3:])
@@ -273,24 +293,24 @@ def _chunked_bwd(spec, res, d_out):
         return jnp.moveaxis(x.reshape(B, n_k, bk, Hkv, D), 1, 0)
 
     with jax.named_scope("attn_core"):
-        d_out = d_out.astype(jnp.float32)
-        qf = q_blocks(q.astype(jnp.float32) * scale)
-        dof = q_blocks(d_out)
+        qc = q_blocks((q.astype(f32) * scale).astype(cdt))
+        doc = q_blocks(d_out.astype(cdt))
         # rowsum(dO * O), with O kept by q block as (n_q, B, Hq, bq, D).
-        delta = (dof * out.reshape(dof.shape)).sum(-1)
+        delta = (doc.astype(f32) * out.reshape(doc.shape)).sum(-1)
         lse = lse.reshape(n_q, B, Hkv, g * bq)
         qsb = jnp.moveaxis(q_segments.reshape(B, n_q, bq), 1, 0)
         ksb = jnp.moveaxis(kv_segments.reshape(B, n_k, bk), 1, 0)
-        kf = kv_blocks(k.astype(jnp.float32))
-        vf = kv_blocks(v.astype(jnp.float32))
+        kc = kv_blocks(k.astype(cdt))
+        vc = kv_blocks(v.astype(cdt))
 
         def pair_step(carry, pair):
             dq, dk, dv = carry
             qi, ki = pair
-            q_blk, do_blk = qf[qi], dof[qi]
+            q_blk, do_blk = qc[qi], doc[qi]
             lse_blk, delta_blk = lse[qi], delta[qi]
-            k_blk, v_blk = kf[ki], vf[ki]
-            s = jnp.einsum("bhmd,bkhd->bhmk", q_blk, k_blk)
+            k_blk, v_blk = kc[ki], vc[ki]
+            s = jnp.einsum("bhmd,bkhd->bhmk", q_blk, k_blk,
+                           preferred_element_type=f32)
             if softcap is not None:
                 t = jnp.tanh(s / softcap)
                 s = softcap * t
@@ -298,13 +318,19 @@ def _chunked_bwd(spec, res, d_out):
             p = jnp.where(
                 mask, jnp.exp(s - lse_blk[..., None]).reshape(
                     B, Hkv, g, bq, bk), 0.0).reshape(s.shape)
-            dp = jnp.einsum("bhmd,bkhd->bhmk", do_blk, v_blk)
-            ds = p * (dp - delta_blk[..., None])
+            dp = jnp.einsum("bhmd,bkhd->bhmk", do_blk, v_blk,
+                            preferred_element_type=f32)
+            p = p.astype(cdt)
+            ds = p.astype(f32) * (dp - delta_blk[..., None])
             if softcap is not None:
                 ds = ds * (1.0 - t * t)
-            dq_blk = jnp.einsum("bhmk,bkhd->bhmd", ds, k_blk) * scale
-            dk_blk = jnp.einsum("bhmk,bhmd->bkhd", ds, q_blk)
-            dv_blk = jnp.einsum("bhmk,bhmd->bkhd", p, do_blk)
+            ds = ds.astype(cdt)
+            dq_blk = jnp.einsum("bhmk,bkhd->bhmd", ds, k_blk,
+                                preferred_element_type=f32) * scale
+            dk_blk = jnp.einsum("bhmk,bhmd->bkhd", ds, q_blk,
+                                preferred_element_type=f32)
+            dv_blk = jnp.einsum("bhmk,bhmd->bkhd", p, do_blk,
+                                preferred_element_type=f32)
             put = jax.lax.dynamic_update_index_in_dim
             return (put(dq, dq[qi] + dq_blk, qi, 0),
                     put(dk, dk[ki] + dk_blk, ki, 0),
@@ -312,8 +338,8 @@ def _chunked_bwd(spec, res, d_out):
 
         kv_major = sorted(spec.live_pairs(n_q, n_k), key=lambda p: p[::-1])
         (dq, dk, dv), _ = jax.lax.scan(
-            pair_step, (jnp.zeros_like(qf), jnp.zeros_like(kf),
-                        jnp.zeros_like(vf)), _pair_indices(kv_major))
+            pair_step, (jnp.zeros(qc.shape, f32), jnp.zeros(kc.shape, f32),
+                        jnp.zeros(vc.shape, f32)), _pair_indices(kv_major))
         dq = jnp.moveaxis(dq.reshape(n_q, B, Hkv, g, bq, D), (0, 4), (1, 2))
         dq = dq.reshape(B, Sq, Hq, D)
         dk = jnp.moveaxis(dk, 0, 1).reshape(B, Sk, Hkv, D)

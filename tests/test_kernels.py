@@ -121,6 +121,9 @@ _GRAD_CASES = {
     # A 4 x 2 grid, as in the train step's default blocks at S = 2048.
     "rect_blocks": (2, 256, 256, 4, 2, 32, True, None, None, True, 0, 64,
                     128, jnp.float32),
+    # The same grid with products at bfloat16.
+    "rect_blocks_bf16": (2, 256, 256, 4, 2, 32, True, None, None, True, 0,
+                         64, 128, jnp.bfloat16),
     # The window leaves out pairs below the diagonal as well as above it.
     "window_rect": (2, 256, 256, 4, 2, 32, True, 48, None, False, 0, 32, 64,
                     jnp.float32),
@@ -135,8 +138,9 @@ _GRAD_CASES = {
 @pytest.mark.parametrize("case", list(_GRAD_CASES.values()),
                          ids=list(_GRAD_CASES))
 def test_flash_xla_grad_matches_reference(case):
-    """The chunked path's own backward against autodiff of the oracle, with
-    several q and kv blocks so the block loops run."""
+    """The chunked path's forward and its own backward against the oracle
+    and autodiff of it, with several q and kv blocks so the block loops
+    run."""
     (B, Sq, Sk, Hq, Hkv, D, causal, window, softcap, segments, q_offset,
      block_q, block_k, dtype) = case
     q, k, v = _qkv(jax.random.PRNGKey(6), B, Sq, Sk, Hq, Hkv, D, dtype)
@@ -153,12 +157,16 @@ def test_flash_xla_grad_matches_reference(case):
         return lambda q, k, v: jnp.sum(
             attn(q, k, v).astype(jnp.float32) * d_out)
 
-    grads = jax.jit(jax.grad(loss(lambda q, k, v: flash_attention(
-        q, k, v, impl="xla", block_q=block_q, block_k=block_k, **kw)),
-        (0, 1, 2)))(q, k, v)
+    def attn(q, k, v):
+        return flash_attention(q, k, v, impl="xla", block_q=block_q,
+                               block_k=block_k, **kw)
+
+    grads = jax.jit(jax.grad(loss(attn), (0, 1, 2)))(q, k, v)
     refs = jax.jit(jax.grad(loss(lambda q, k, v: attention_reference(
         q, k, v, **kw)), (0, 1, 2)))(q, k, v)
     assert Sq // block_q > 1 and Sk // block_k > 1
+    _assert_close(jax.jit(attn)(q, k, v), attention_reference(q, k, v, **kw),
+                  dtype)
     for g, r in zip(grads, refs):
         assert g.dtype == dtype
         _assert_close(g, r, dtype)
@@ -176,6 +184,48 @@ def test_flash_xla_vjp_keeps_no_score_block():
         impl="xla", block_q=bq, block_k=bk), q, k, v)
     sizes = [x.size for x in jax.tree.leaves(vjp)]
     assert sizes and max(sizes) < B * Hq * bq * bk, sizes
+
+
+def _dot_generals(jaxpr):
+    """Every dot_general of a jaxpr and of the jaxprs its equations hold
+    (scan bodies, custom-VJP calls, nested jits)."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _dot_generals(sub)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_flash_xla_products_take_the_input_dtype(dtype):
+    """Each product of the chunked path and of its backward (QK^T and PV,
+    then the recomputed QK^T, dO V^T, dS K, dS^T Q and P^T dO) takes both
+    operands at the input dtype, the score-sized P and dS among them, and
+    accumulates in float32: at bfloat16 no operand is float32, and at
+    float32 every one is, as before the rule."""
+    B, S, Hq, Hkv, D, bq, bk = 2, 256, 4, 2, 32, 64, 128
+    q, k, v = _qkv(jax.random.PRNGKey(11), B, S, S, Hq, Hkv, D, dtype)
+    segs = jnp.zeros((B, S), jnp.int32).at[:, S // 3:].set(1)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, causal=True, q_segments=segs, kv_segments=segs,
+            impl="xla", block_q=bq, block_k=bk).astype(jnp.float32))
+
+    dots = list(_dot_generals(
+        jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, v).jaxpr))
+    assert len(dots) == 7
+    score = B * Hq * bq * bk
+    sizes = {x.aval.size for eqn in dots for x in eqn.invars}
+    assert score in sizes and B * bq * Hq * D in sizes
+    for eqn in dots:
+        assert [x.aval.dtype for x in eqn.invars] == [jnp.dtype(dtype)] * 2
+        assert eqn.params["preferred_element_type"] == jnp.float32
+        assert eqn.outvars[0].aval.dtype == jnp.float32
 
 
 def _exhaustive_live_pairs(spec, n_q, n_k):

@@ -312,6 +312,16 @@ def test_attention_trace_counts_its_live_block_pairs():
     assert obs.counters() == {"attn.pairs_live": 6, "attn.pairs_all": 8}
 
 
+def test_attention_trace_counts_narrow_operands():
+    """The same trace at bfloat16: the products take narrow operands."""
+    obs.enable()
+    x = jax.ShapeDtypeStruct((2, 64, 4, 16), jnp.bfloat16)
+    jax.eval_shape(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, impl="xla", block_q=16, block_k=32), x, x, x)
+    assert obs.counters() == {"attn.pairs_live": 6, "attn.pairs_all": 8,
+                              "attn.operands_narrow": 1}
+
+
 def test_ssd_products_carry_ssm_ssd():
     """Every matmul of the SSD scan carries ``ssm/ssd``, in the forward,
     its remat recompute and the backward, so none is left to the layer
